@@ -26,7 +26,9 @@ from .common import emit, time_fn
 def _mesh1():
     """1-device ("data", "model") mesh: activates the shard_map recompress
     path (and the compress-phase sharding constraints) on a single CPU."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import auto_mesh
+
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def _setup(n_side, a=0.09, nu22=1.0):

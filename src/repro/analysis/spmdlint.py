@@ -110,7 +110,7 @@ def _eqn_source(eqn) -> tuple[str | None, int | None]:
     """Best-effort (file, line) of the user frame that traced this eqn."""
     try:
         from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is not None:
             line = getattr(frame, "start_line", None) or \
                 getattr(frame, "line_num", None)

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import linalg
 from .matern import matern_correlation, parsimonious_nu_matrix, parsimonious_rho
 
 
@@ -81,6 +82,18 @@ def _concrete_halfint(nu):
     return v if v in (0.5, 1.5, 2.5) else None
 
 
+def _general_orders(u, nu_ij, pairs):
+    """{(i, j): M_{nu_ij}(u)} for the pairs without a closed form, from ONE
+    vectorized K_nu call: the compiled program then holds one copy of its
+    series and continued-fraction loops instead of one per pair (each copy
+    of the f64 loops takes ~45 s to compile for v5e)."""
+    if not pairs:
+        return {}
+    nus = jnp.stack([nu_ij[i, j] for i, j in pairs])
+    corr = matern_correlation(u, nus.reshape((-1,) + (1,) * u.ndim))
+    return {ij: corr[g] for g, ij in enumerate(pairs)}
+
+
 def _pair_correlations(dists, params: MaternParams, d_spatial: int = 2):
     """Correlation stack for every ordered variable pair.
 
@@ -100,19 +113,23 @@ def _pair_correlations(dists, params: MaternParams, d_spatial: int = 2):
     u = dists / params.a
 
     # Only p(p+1)/2 distinct orders; evaluate each once then mirror.
-    iu, ju = np.triu_indices(p)
+    pairs = list(zip(*np.triu_indices(p)))
+    halves = {ij: _concrete_halfint(nu_ij[ij]) for ij in pairs}
+    vals = _general_orders(u, nu_ij, [ij for ij in pairs if halves[ij] is None])
     corr = jnp.zeros((p, p) + dists.shape,
                      dtype=jnp.result_type(u.dtype, jnp.float32))
-    for i, j in zip(iu, ju):
-        half = _concrete_halfint(nu_ij[i, j])
-        if half is not None:
-            c = matern_correlation_halfint(u, half)
-        else:
-            c = matern_correlation(u, nu_ij[i, j])
+    for i, j in pairs:
+        c = vals.get((i, j))
+        if c is None:
+            c = matern_correlation_halfint(u, halves[i, j])
         corr = corr.at[i, j].set(c)
         if i != j:
             corr = corr.at[j, i].set(c)
     return rho[(...,) + (None,) * dists.ndim] * corr
+
+
+# Locations per column panel when build_sigma assembles a large Sigma.
+_PANEL_LOCS = 512
 
 
 def build_sigma(locs, params: MaternParams, representation: str = "I",
@@ -122,24 +139,41 @@ def build_sigma(locs, params: MaternParams, representation: str = "I",
     representation "I": entry ((l, i), (r, j)) at [l*p + i, r*p + j]
     representation "II": at [i*n + l, j*n + r]
     """
-    if dists is None:
-        dists = pairwise_distances(locs)
-    n = dists.shape[0]
     p = params.p
-    sig = jnp.sqrt(params.sigma2)
-    amp = sig[:, None] * sig[None, :]
-    blocks = _pair_correlations(dists, params, d_spatial)  # (p, p, n, n)
-    blocks = amp[:, :, None, None] * blocks
-    if representation.upper() == "I":
-        # (p, p, n, n) -> (n, p, n, p) -> (np, np)
-        sigma = jnp.transpose(blocks, (2, 0, 3, 1)).reshape(n * p, n * p)
-    elif representation.upper() == "II":
-        sigma = jnp.transpose(blocks, (0, 2, 1, 3)).reshape(n * p, n * p)
-    else:
+    rep = representation.upper()
+    if rep not in ("I", "II"):
         raise ValueError(f"unknown representation {representation!r}")
+    n = (locs if dists is None else dists).shape[0]
+    if (dists is None and rep == "I" and n > _PANEL_LOCS
+            and n % _PANEL_LOCS == 0 and linalg._on_tpu()):
+        # Column panels under a fori_loop: the K_nu loops then carry
+        # (p, p, n, _PANEL_LOCS) arrays instead of (p, p, n, n) ones, which
+        # keeps the dense m = 8192 program inside a v5e's HBM.
+        locs = jnp.asarray(locs)
+        nb = _PANEL_LOCS * p
+
+        def panel(j, sigma):
+            col = build_sigma_column(locs, j, _PANEL_LOCS, params,
+                                     d_spatial=d_spatial)
+            return jax.lax.dynamic_update_slice(sigma, col, (0, j * nb))
+
+        dtype = jnp.result_type(locs.dtype, params.sigma2.dtype, jnp.float32)
+        sigma = jax.lax.fori_loop(0, n // _PANEL_LOCS, panel,
+                                  jnp.zeros((n * p, n * p), dtype))
+    else:
+        if dists is None:
+            dists = pairwise_distances(locs)
+        sig = jnp.sqrt(params.sigma2)
+        amp = sig[:, None] * sig[None, :]
+        blocks = _pair_correlations(dists, params, d_spatial)  # (p, p, n, n)
+        blocks = amp[:, :, None, None] * blocks
+        # (p, p, n, n) -> (n, p, n, p) [I] or (p, n, p, n) [II] -> (np, np)
+        perm = (2, 0, 3, 1) if rep == "I" else (0, 2, 1, 3)
+        sigma = jnp.transpose(blocks, perm).reshape(n * p, n * p)
     # `is not None`, never truthiness: the MLE traces the nugget (spmdlint A1).
     if nugget is not None:
-        sigma = sigma + nugget * jnp.eye(n * p, dtype=sigma.dtype)
+        idx = jnp.arange(n * p)
+        sigma = sigma.at[idx, idx].add(nugget)
     return sigma
 
 
@@ -155,8 +189,9 @@ def build_sigma_panel(locs_rows, locs_cols, params: MaternParams,
     the *generator*, not the matrix.
 
     ``gen="pallas"`` routes concrete half-integer pair smoothnesses through
-    the ``kernels.matern_tile`` Pallas kernel; general (or traced) orders fall
-    back to the XLA K_nu path per pair, so the knob is always safe to set.
+    the ``kernels.matern_tile`` Pallas kernel where it compiles (f32, or
+    the interpreter off TPU); general (or traced) orders and f64 locations
+    on TPU take the XLA path, so the knob is always safe to set.
     """
     from .matern import matern_correlation_halfint
 
@@ -170,23 +205,26 @@ def build_sigma_panel(locs_rows, locs_cols, params: MaternParams,
     amp = rho * (sig[:, None] * sig[None, :])
     inv_a = 1.0 / params.a
     use_pallas = gen == "pallas" and locs_rows.shape[1] == 2
-    dists = None
+    if use_pallas:
+        from ..kernels.matern_tile import compiles_for
+        use_pallas = compiles_for(locs_rows.dtype)
 
-    iu, ju = np.triu_indices(p)
+    pairs = list(zip(*np.triu_indices(p)))
+    halves = {ij: _concrete_halfint(nu_ij[ij]) for ij in pairs}
+    general = [ij for ij in pairs if halves[ij] is None]
+    if general or not use_pallas:
+        u = pairwise_distances(locs_rows, locs_cols) * inv_a
+    vals = _general_orders(u, nu_ij, general) if general else {}
     corr = jnp.zeros((p, p, R, C),
                      dtype=jnp.result_type(locs_rows.dtype, jnp.float32))
-    for i, j in zip(iu, ju):
-        half = _concrete_halfint(nu_ij[i, j])
-        if use_pallas and half is not None:
+    for i, j in pairs:
+        c = vals.get((i, j))
+        if c is None and use_pallas:
             from ..kernels.matern_tile import matern_tile
-            c = matern_tile(locs_rows, locs_cols, inv_a, 1.0, nu=half,
-                            block_n=block, block_m=block)
-        else:
-            if dists is None:
-                dists = pairwise_distances(locs_rows, locs_cols)
-            u = dists * inv_a
-            c = (matern_correlation_halfint(u, half) if half is not None
-                 else matern_correlation(u, nu_ij[i, j]))
+            c = matern_tile(locs_rows, locs_cols, inv_a, 1.0,
+                            nu=halves[i, j], block_n=block, block_m=block)
+        elif c is None:
+            c = matern_correlation_halfint(u, halves[i, j])
         corr = corr.at[i, j].set(c)
         if i != j:
             corr = corr.at[j, i].set(c)
@@ -195,7 +233,8 @@ def build_sigma_panel(locs_rows, locs_cols, params: MaternParams,
 
 
 def build_sigma_column(locs, j, nbl: int, params: MaternParams,
-                       d_spatial: int = 2, gen: str = "xla", block: int = 256):
+                       d_spatial: int = 2, gen: str = "xla", block: int = 256,
+                       row_block: int = 0):
     """One Representation-I *tile-grid column* panel, generator-direct.
 
     Returns the (m, nb) slice ``build_sigma(locs, ...)[:, j*nb:(j+1)*nb]``
@@ -204,11 +243,28 @@ def build_sigma_column(locs, j, nbl: int, params: MaternParams,
     (core.dist_tlr.dist_compress_tiles) runs it under lax.fori_loop — while
     ``nbl`` (locations per tile) must be static so the slice has a static
     shape.
+
+    ``row_block`` > 0 generates the panel ``row_block`` locations at a time
+    under a fori_loop: the K_nu loops then carry (p, p, row_block, nbl)
+    arrays instead of (p, p, n, nbl) ones.  In one piece a v5e program
+    needs 2.4 GiB of temporaries for them at n = 4096, nbl = 1024 in f64.
     """
     locs = jnp.asarray(locs)
     cols = jax.lax.dynamic_slice_in_dim(locs, j * nbl, nbl, axis=0)
-    return build_sigma_panel(locs, cols, params, d_spatial=d_spatial, gen=gen,
-                             block=block)
+    n, p = locs.shape[0], params.p
+    if not (0 < row_block < n and n % row_block == 0):
+        return build_sigma_panel(locs, cols, params, d_spatial=d_spatial,
+                                 gen=gen, block=block)
+
+    def rows(i, out):
+        lr = jax.lax.dynamic_slice_in_dim(locs, i * row_block, row_block, 0)
+        pan = build_sigma_panel(lr, cols, params, d_spatial=d_spatial,
+                                gen=gen, block=block)
+        return jax.lax.dynamic_update_slice(out, pan, (i * row_block * p, 0))
+
+    dtype = jnp.result_type(locs.dtype, params.sigma2.dtype, jnp.float32)
+    return jax.lax.fori_loop(0, n // row_block, rows,
+                             jnp.zeros((n * p, nbl * p), dtype))
 
 
 def build_correlation_matrix(locs, a, nu, nugget: float | None = None,

@@ -65,7 +65,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..distribution.block_cyclic import (PairLayout, grid_to_pairs,
@@ -75,6 +74,7 @@ from ..distribution.block_cyclic import (PairLayout, grid_to_pairs,
 from ..distribution.compress_svd import (sharded_truncate_svd,
                                          svd_truncate_batch)
 from ..distribution.pair_qr import warn_fallback_once
+from .linalg import cholesky, solve_lower
 from .covariance import build_sigma_column, build_sigma_panel
 from .likelihood import LoglikResult
 from .precision import resolve_policy
@@ -258,8 +258,8 @@ def dist_compress_tiles(locs, params, *, tile_size: int = 0, tol: float = 1e-7,
     def body(g, carry):
         diag, u, v, ranks = carry
         panel = build_sigma_column(locs, g, cb * nbl, params,
-                                   d_spatial=d_spatial, gen=gen,
-                                   block=nb)                  # (m, cb*nb)
+                                   d_spatial=d_spatial, gen=gen, block=nb,
+                                   row_block=nbl if mesh is None else 0)
         panel = _constrain(panel, mesh, P(row, "model"))
         tiles = panel.reshape(T, nb, cb, nb).transpose(2, 0, 1, 3)
         # SVD input down-cast to U/V storage dtype; diagonal tiles below
@@ -383,11 +383,11 @@ def _compress_tiles_pair_sharded(locs, params, *, layout: PairLayout, nb, nbl,
 
         return indexed_scan(step, G, (u_l, v_l, r_l))
 
-    sweep = shard_map(local, mesh,
-                      in_specs=(pspec, pspec, rspec, ospec, ospec,
-                                P(None, None), P()),
-                      out_specs=(pspec, pspec, rspec),
-                      check_rep=False)
+    sweep = jax.shard_map(local, mesh=mesh,
+                          in_specs=(pspec, pspec, rspec, ospec, ospec,
+                                    P(None, None), P()),
+                          out_specs=(pspec, pspec, rspec),
+                          check_vma=False)
 
     if uv_dtype is None:
         uv_dtype = dtype
@@ -491,7 +491,7 @@ def dist_tlr_cholesky(diag, u, v, ranks=None, *, tol: float = 1e-7,
             diag, u, v, ranks, status = out
         else:
             diag, u, v, ranks = out
-    lkk = jnp.linalg.cholesky(diag[T - 1])
+    lkk = cholesky(diag[T - 1])
     if track_status:
         status = status.update_potrf(lkk)
     diag = diag.at[T - 1].set(lkk)
@@ -533,7 +533,7 @@ def dist_tlr_cholesky_pairs(diag, up, vp, ranks, *, layout: PairLayout,
             diag, up, vp, ranks, status = out
         else:
             diag, up, vp, ranks = out
-    lkk = jnp.linalg.cholesky(diag[T - 1])
+    lkk = cholesky(diag[T - 1])
     if track_status:
         status = status.update_potrf(lkk)
     diag = diag.at[T - 1].set(lkk)
@@ -638,7 +638,7 @@ def _tlr_cholesky_super_pairs(diag, up, vp, ranks, *, layout: PairLayout,
             else:
                 dh, uh, vh, rh = out
         if s == super_panels - 1:
-            lkk = jnp.linalg.cholesky(dh[ts - 1])
+            lkk = cholesky(dh[ts - 1])
             if track_status:
                 status = status.update_potrf(lkk)
             dh = dh.at[ts - 1].set(lkk)
@@ -695,7 +695,7 @@ def dist_tlr_solve_lower_pairs(diag_l, up, vp, z, *, layout: PairLayout):
         z, out = carry
         lkk = lax.dynamic_index_in_dim(diag_l, k, 0, keepdims=False)
         zk = lax.dynamic_index_in_dim(z, k, 0, keepdims=False)
-        ak = lax.linalg.triangular_solve(lkk, zk, left_side=True, lower=True)
+        ak = solve_lower(lkk, zk)
         out = lax.dynamic_update_index_in_dim(out, ak, k, 0)
         pcol = lax.dynamic_index_in_dim(pos, k, 1, keepdims=False)
         uk = up.at[pcol].get(mode="fill", fill_value=0.0)
@@ -735,8 +735,7 @@ def dist_tlr_solve_upper_pairs(diag_l, up, vp, y, *, layout: PairLayout):
         s = jnp.einsum("tnk,tkr->nr", vk, wu)
         lkk = lax.dynamic_index_in_dim(diag_l, k, 0, keepdims=False)
         yk = lax.dynamic_index_in_dim(y, k, 0, keepdims=False)
-        xk = lax.linalg.triangular_solve(lkk, yk - s, left_side=True,
-                                         lower=True, transpose_a=True)
+        xk = solve_lower(lkk, yk - s, transpose=True)
         return lax.dynamic_update_index_in_dim(out, xk, k, 0)
 
     out = indexed_scan(body, T, jnp.zeros_like(y))

@@ -16,6 +16,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .linalg import cholesky, solve_lower
 from .covariance import (MaternParams, build_correlation_matrix, build_sigma,
                          pairwise_distances)
 from .recovery import FactorStatus, init_status
@@ -40,7 +41,7 @@ def loglik_from_chol(chol, z, keep_chol: bool = False,
     if status is None:
         status = init_status(chol.dtype).update_potrf(chol)
     logdet = 2.0 * jnp.sum(jnp.log(jnp.diagonal(chol)))
-    alpha = jax.scipy.linalg.solve_triangular(chol, z, lower=True)
+    alpha = solve_lower(chol, z)
     quad = jnp.sum(alpha * alpha, axis=-1)
     ll = -0.5 * (m * math.log(2.0 * math.pi) + logdet + quad)
     return LoglikResult(ll, logdet, quad, chol if keep_chol else None, status)
@@ -52,7 +53,7 @@ def exact_loglik(locs, z, params: MaternParams, representation: str = "I",
     """Dense-Cholesky evaluation of Eq. (1)."""
     sigma = build_sigma(locs, params, representation=representation,
                         nugget=nugget, dists=dists)
-    chol = jnp.linalg.cholesky(sigma)
+    chol = cholesky(sigma)
     return loglik_from_chol(chol, z, keep_chol=keep_chol)
 
 
@@ -67,12 +68,12 @@ def profile_variances(dists, z, a, nu, p: int, nugget: float = 0.0,
 
     def one(i):
         r = build_correlation_matrix(None, a, nu[i], nugget=nugget, dists=dists)
-        chol = jnp.linalg.cholesky(r)
+        chol = cholesky(r)
         if representation.upper() == "I":
             zi = z[i::p]
         else:
             zi = jax.lax.dynamic_slice_in_dim(z, i * n, n)
-        alpha = jax.scipy.linalg.solve_triangular(chol, zi, lower=True)
+        alpha = solve_lower(chol, zi)
         return jnp.sum(alpha * alpha) / n
 
     return jnp.stack([one(i) for i in range(p)])

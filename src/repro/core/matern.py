@@ -35,34 +35,44 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-# Euler–Mascheroni constant (used in the mu -> 0 limit of the Temme series).
-_EULER_GAMMA = 0.5772156649015328606
-
 # ---------------------------------------------------------------------------
 # K_nu — modified Bessel function of the second kind, real order.
 # ---------------------------------------------------------------------------
+
+
+# Chebyshev coefficients of Temme's Gamma_1 and Gamma_2 on |mu| <= 1/2
+# (Numerical Recipes, ``beschb``).
+_GAM1_CHEB = (-1.142022680371168e0, 6.5165112670737e-3, 3.087090173086e-4,
+              -3.4706269649e-6, 6.9437664e-9, 3.67795e-11, -1.356e-13)
+_GAM2_CHEB = (1.843740587300905e0, -7.68528408447867e-2, 1.2719271366546e-3,
+              -4.9717367042e-6, -3.31261198e-8, 2.423096e-10, -1.702e-13,
+              -1.49e-15)
+
+
+def _chebev(coeffs, y):
+    """Clenshaw sum of a Chebyshev series on [-1, 1] (NR ``chebev``)."""
+    d = dd = jnp.zeros_like(y)
+    for c in coeffs[:0:-1]:
+        d, dd = 2.0 * y * d - dd + c, d
+    return y * d - dd + 0.5 * coeffs[0]
 
 
 def _chepolish(mu, dtype):
     """gam1, gam2, gampl, gammi used by the Temme series.
 
     gampl = 1/Gamma(1+mu),   gammi = 1/Gamma(1-mu)
-    gam1  = (gammi - gampl) / (2 mu)      (-> EulerGamma as mu -> 0)
+    gam1  = (gammi - gampl) / (2 mu)      (-> -EulerGamma as mu -> 0)
     gam2  = (gammi + gampl) / 2
+
+    Chebyshev series in 8 mu^2 - 1 rather than two log-gamma evaluations:
+    no cancellation in gam1 as mu -> 0, and a handful of multiply-adds
+    where each f64 log-gamma adds ~12 s to a v5e compile.
     """
     mu = jnp.asarray(mu, dtype)
-    gampl = jnp.exp(-jax.scipy.special.gammaln(1.0 + mu))
-    gammi = jnp.exp(-jax.scipy.special.gammaln(1.0 - mu))
-    small = jnp.abs(mu) < 1e-6
-    # Series: 1/Gamma(1-mu) - 1/Gamma(1+mu) = -2*gamma*mu + O(mu^3),
-    # so gam1 -> -EulerGamma as mu -> 0 (Temme's Gamma_1).
-    gam1 = jnp.where(
-        small,
-        -_EULER_GAMMA + mu * mu * 0.0,  # first-order limit; O(mu^2) < 1e-12
-        (gammi - gampl) / jnp.where(small, 1.0, 2.0 * mu),
-    )
-    gam2 = 0.5 * (gammi + gampl)
-    return gam1, gam2, gampl, gammi
+    y = 8.0 * mu * mu - 1.0
+    gam1 = _chebev(_GAM1_CHEB, y)
+    gam2 = _chebev(_GAM2_CHEB, y)
+    return gam1, gam2, gam2 - mu * gam1, gam2 + mu * gam1
 
 
 def _kv_temme_series(mu, x, max_iter=200):
@@ -84,12 +94,16 @@ def _kv_temme_series(mu, x, max_iter=200):
     fact2 = jnp.where(jnp.abs(e) < 1e-12, 1.0,
                       jnp.sinh(e) / jnp.where(jnp.abs(e) < 1e-12, 1.0, e))
     gam1, gam2, gampl, gammi = _chepolish(mu, dtype)
-    ff0 = fact * (gam1 * jnp.cosh(e) + gam2 * fact2 * d)
     ee = jnp.exp(e)
+    ff0 = fact * (gam1 * (0.5 * (ee + 1.0 / ee)) + gam2 * fact2 * d)
     p0 = 0.5 * ee / gampl
     q0 = 0.5 / (ee * gammi)
     c0 = jnp.ones_like(x)
     d2 = x2 * x2
+    # an array mu (one order per leading slice) broadcasts against x; the
+    # loop carry takes the joint shape
+    shape = jnp.broadcast_shapes(jnp.shape(mu), x.shape)
+    ff0, p0, q0, c0 = (jnp.broadcast_to(t, shape) for t in (ff0, p0, q0, c0))
 
     def cond(carry):
         i = carry[0]
@@ -111,7 +125,7 @@ def _kv_temme_series(mu, x, max_iter=200):
         return i + 1, ff, p, q, c, ksum, ksum1, done
 
     init = (jnp.asarray(1, jnp.int32), ff0, p0, q0, c0, ff0, p0,
-            jnp.zeros_like(x, dtype=bool))
+            jnp.zeros(shape, bool))
     # spmdlint: ignore[R5] early-exit series convergence is the point (i32 carry, elementwise); differentiable paths use kv_half_integer closed forms
     out = lax.while_loop(cond, body, init)
     ksum, ksum1 = out[5], out[6]
@@ -125,6 +139,12 @@ def _kv_steed_cf2(mu, x, max_iter=400):
 
     Early-exit while_loop; convergence slows toward x -> 2+ (max_iter bounds
     the worst case, typical counts are < 60).
+
+    NR's recurrence carries c_i (growing like 2^i) and q_i (shrinking like
+    2^-i) and adds only their product.  Here the carry is the products
+    ``cq1 = c_{i-1} q_{i-1}`` and ``cq2 = c_{i-1} q_i``, which stay bounded:
+    TPU f64 is emulated in pairs of f32 words, whose exponent range c_i and
+    q_i leave after ~128 terms (inf * 0 = NaN near x = 2).
     """
     dtype = x.dtype
     eps = jnp.finfo(dtype).eps
@@ -133,10 +153,9 @@ def _kv_steed_cf2(mu, x, max_iter=400):
     d0 = 1.0 / b0
     h0 = d0
     delh0 = d0
-    q1_0 = jnp.zeros_like(x)
-    q2_0 = jnp.ones_like(x)
+    cq1_0 = jnp.zeros_like(x)
+    cq2_0 = a1 * jnp.ones_like(x)
     q0 = a1 * jnp.ones_like(x)
-    c0 = a1 * jnp.ones_like(x)
     s0 = 1.0 + q0 * delh0
 
     def cond(carry):
@@ -145,13 +164,12 @@ def _kv_steed_cf2(mu, x, max_iter=400):
         return (i <= max_iter + 1) & ~jnp.all(done)
 
     def body(carry):
-        i, a, b, c, d, h, delh, q, q1, q2, s, done = carry
+        i, a, b, d, h, delh, q, cq1, cq2, s, done = carry
         fi = i.astype(dtype)
         a = a - 2.0 * (fi - 1.0)
-        c = -a * c / fi
-        qnew = (q1 - b * q2) / a
-        q1, q2 = q2, qnew
-        q = q + c * qnew
+        cq = -(cq1 - b * cq2) / fi                  # c_i q_{i+1}
+        cq1, cq2 = -a * cq2 / fi, cq
+        q = q + cq
         b = b + 2.0
         d = 1.0 / (b + a * d)
         delh = (b * d - 1.0) * delh
@@ -161,16 +179,16 @@ def _kv_steed_cf2(mu, x, max_iter=400):
         h = jnp.where(done, h, hn)
         s = jnp.where(done, s, sn)
         done = done | (jnp.abs(dels / sn) < eps)
-        return i + 1, a, b, c, d, h, delh, q, q1, q2, s, done
+        return i + 1, a, b, d, h, delh, q, cq1, cq2, s, done
 
-    init = (
-        jnp.asarray(2, jnp.int32),
-        -a1 * jnp.ones_like(x), b0, c0, d0, h0, delh0, q0, q1_0, q2_0, s0,
-        jnp.zeros_like(x, dtype=bool),
-    )
+    shape = jnp.broadcast_shapes(jnp.shape(mu), x.shape)
+    init = (jnp.asarray(2, jnp.int32),) + tuple(
+        jnp.broadcast_to(t, shape) for t in (
+            -a1 * jnp.ones_like(x), b0, d0, h0, delh0, q0, cq1_0, cq2_0,
+            s0)) + (jnp.zeros(shape, bool),)
     # spmdlint: ignore[R5] early-exit CF2 convergence is the point (i32 carry, elementwise); differentiable paths use kv_half_integer closed forms
     out = lax.while_loop(cond, body, init)
-    h, s = out[5], out[10]
+    h, s = out[4], out[9]
     h = a1 * h
     rkmu = jnp.sqrt(jnp.asarray(math.pi, dtype) / (2.0 * x)) * jnp.exp(-x) / s
     rk1 = rkmu * (mu + x + 0.5 - h) / x
@@ -181,9 +199,11 @@ def _kv_steed_cf2(mu, x, max_iter=400):
 def kv(nu, x):
     """Modified Bessel function of the second kind K_nu(x).
 
-    nu: scalar (may be traced) > 0. x: array-like > 0.
-    Mirrors Numerical-Recipes ``bessik``: reduce nu = nl + mu with |mu| <= 1/2,
-    evaluate K_mu, K_{mu+1} (Temme for x<=2, CF2 for x>2), then recur upward.
+    nu: > 0, a scalar or an array that broadcasts against x (may be
+    traced).  x: array-like > 0.  Mirrors Numerical-Recipes ``bessik``:
+    reduce nu = nl + mu with |mu| <= 1/2, evaluate K_mu, K_{mu+1} (Temme
+    for x<=2, CF2 for x>2), then recur upward.  Several orders in one call
+    share one copy of the loops in the compiled program.
     """
     x = jnp.asarray(x)
     dtype = x.dtype if jnp.issubdtype(x.dtype, jnp.floating) else jnp.result_type(float)
@@ -203,10 +223,11 @@ def kv(nu, x):
         rkmu, rk1 = carry
         fi = i.astype(dtype)
         rktemp = (mu + fi) * (2.0 / xs) * rk1 + rkmu
-        return rk1, rktemp
+        act = i <= nl                   # orders needing fewer steps hold
+        return jnp.where(act, rk1, rkmu), jnp.where(act, rktemp, rk1)
 
-    # spmdlint: ignore[R5] nl = floor(nu + 0.5) recurrences — nu may be traced, so the trip count is data-dependent by design
-    rkmu, rk1 = lax.fori_loop(1, nl + 1, recur, (rkmu, rk1))
+    # spmdlint: ignore[R5,A2] nl = floor(nu + 0.5) recurrences — nu may be traced, so the trip count is data-dependent by design
+    rkmu, rk1 = lax.fori_loop(1, jnp.max(nl) + 1, recur, (rkmu, rk1))
     return rkmu
 
 
@@ -312,13 +333,17 @@ def parsimonious_rho(nus, beta, d: int = 2):
     dtype = jnp.result_type(nus.dtype, beta.dtype, float)
     nus = nus.astype(dtype)
     beta = beta.astype(dtype)
-    gln = jax.scipy.special.gammaln
     half_d = jnp.asarray(0.5 * d, dtype)
-    gmarg = 0.5 * (gln(nus + half_d) - gln(nus))  # log sqrt(G(nu+d/2)/G(nu))
     nu_ij = parsimonious_nu_matrix(nus)
-    logfac = gmarg[:, None] + gmarg[None, :] + gln(nu_ij) - gln(nu_ij + half_d)
-    rho = beta * jnp.exp(logfac)
     p = nus.shape[0]
+    # one log-gamma over all four argument sets: each f64 log-gamma op adds
+    # ~12 s to a v5e compile
+    g = jax.scipy.special.gammaln(jnp.concatenate(
+        [nus + half_d, nus, nu_ij.ravel(), nu_ij.ravel() + half_d]))
+    gmarg = 0.5 * (g[:p] - g[p:2 * p])      # log sqrt(G(nu+d/2)/G(nu))
+    g_ij = (g[2 * p:2 * p + p * p] - g[2 * p + p * p:]).reshape(p, p)
+    logfac = gmarg[:, None] + gmarg[None, :] + g_ij
+    rho = beta * jnp.exp(logfac)
     return jnp.where(jnp.eye(p, dtype=bool), jnp.ones_like(rho), rho)
 
 

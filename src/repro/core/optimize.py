@@ -1,11 +1,13 @@
 """Derivative-free optimization (the NLOPT role in the paper's stack).
 
 The paper calls NLOPT (BOBYQA) because dK_nu/dnu has no stable closed form.
-We implement a jit-compatible Nelder–Mead simplex in pure JAX.  Control flow
-uses lax.cond so each iteration evaluates only the simplex points it actually
-needs (~2 objective evaluations per iteration on average) — each objective
-evaluation is one Sigma build + Cholesky, exactly the unit the paper
-benchmarks as "one iteration of the MLE optimization".
+We implement a Nelder–Mead simplex whose control flow runs on the host and
+calls the (jitted) objective only at the points each iteration needs (~2
+objective evaluations per iteration on average) — each objective evaluation
+is one Sigma build + Cholesky, exactly the unit the paper benchmarks as "one
+iteration of the MLE optimization".  The objective is one compiled program
+that every evaluation reuses, and the optimizer never holds more than one
+evaluation's memory.
 
 Fault tolerance (robustness PR):
 
@@ -18,20 +20,20 @@ Fault tolerance (robustness PR):
   step, pulling the simplex back into the feasible region.
 * ``has_aux`` threads an auxiliary pytree (clamp/retry counters from
   ``mle.make_objective``) out of every evaluation; the running tree-sum
-  rides the loop carry and is returned on ``NMResult.aux``.
+  is returned on ``NMResult.aux``.
 * ``init_state`` / ``NMResult.state`` make the loop resumable: run a
   bounded segment, checkpoint the ``NMState``, resume later —
   ``multistart_nelder_mead`` uses this for crash-tolerant multistart MLE.
 """
 from __future__ import annotations
 
+import math
 from typing import Callable, NamedTuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 
 class NMState(NamedTuple):
@@ -58,7 +60,12 @@ def _order(simplex, values):
 
 
 def _wrap_eval(fn: Callable, has_aux: bool):
-    """Sanitizing evaluation: returns (value, aux) with NaN/inf -> +inf."""
+    """Sanitizing evaluation: returns (value, aux) with NaN/inf -> +inf.
+
+    A jitted ``fn`` is called as it is, so the optimizer runs the caller's
+    compiled objective; anything else is jitted here."""
+    fn = fn if hasattr(fn, "lower") else jax.jit(fn)
+
     def ev(x):
         out = fn(x)
         if has_aux:
@@ -66,8 +73,8 @@ def _wrap_eval(fn: Callable, has_aux: bool):
         else:
             val, aux = out, jnp.zeros((), jnp.int32)
         val = jnp.asarray(val)
-        val = jnp.where(jnp.isfinite(val), val,
-                        jnp.asarray(jnp.inf, val.dtype))
+        if not math.isfinite(float(val)):
+            val = jnp.asarray(jnp.inf, val.dtype)
         return val, aux
     return ev
 
@@ -76,9 +83,14 @@ def _tree_add(a, b):
     return jax.tree.map(jnp.add, a, b)
 
 
-def _tree_sum(batched):
-    """Sum a vmapped aux batch over its leading axis (dtype-preserving)."""
-    return jax.tree.map(lambda x: jnp.sum(x, axis=0, dtype=x.dtype), batched)
+def _eval_rows(ev, points):
+    """Evaluate the rows of ``points`` one after another: one compiled
+    objective, and one evaluation's memory at a time."""
+    outs = [ev(x) for x in points]
+    aux = outs[0][1]
+    for _, a in outs[1:]:
+        aux = _tree_add(aux, a)
+    return jnp.stack([v for v, _ in outs]), aux
 
 
 def nm_init_state(fn: Callable, x0, *, initial_radius: float = 0.25,
@@ -93,10 +105,9 @@ def nm_init_state(fn: Callable, x0, *, initial_radius: float = 0.25,
     m = x0.shape[0]
     steps = initial_radius * jnp.where(jnp.abs(x0) > 1e-8, jnp.abs(x0), 1.0)
     simplex = jnp.concatenate([x0[None], x0[None] + jnp.diag(steps)], axis=0)
-    values, auxs = jax.vmap(ev)(simplex)
+    values, aux = _eval_rows(ev, simplex)
     simplex, values = _order(simplex, values)
-    return NMState(simplex, values, jnp.asarray(m + 1), jnp.asarray(0),
-                   _tree_sum(auxs))
+    return NMState(simplex, values, jnp.asarray(m + 1), jnp.asarray(0), aux)
 
 
 def nelder_mead(fn: Callable, x0, *, max_iters: int = 200,
@@ -105,6 +116,9 @@ def nelder_mead(fn: Callable, x0, *, max_iters: int = 200,
                 init_state: NMState | None = None) -> NMResult:
     """Minimize ``fn`` (scalar, jax-traceable) from x0 (shape (m,)).
 
+    The simplex logic runs on the host and calls ``fn`` once per point it
+    needs, so a jitted ``fn`` is compiled once and holds one evaluation's
+    memory: an MLE objective is a whole TLR factorization.
     With ``has_aux=True`` the objective returns ``(value, aux_pytree)`` and
     the tree-sum of every evaluation's aux is returned on ``result.aux``.
     ``init_state`` resumes a previous run's ``result.state`` (the loop
@@ -115,97 +129,77 @@ def nelder_mead(fn: Callable, x0, *, max_iters: int = 200,
     m = x0.shape[0]
 
     if init_state is None:
-        state = nm_init_state(fn, x0, initial_radius=initial_radius,
-                              has_aux=has_aux)
-    else:
-        state = init_state
+        init_state = nm_init_state(fn, x0, initial_radius=initial_radius,
+                                   has_aux=has_aux)
+    simplex, values, aux = init_state.simplex, init_state.values, init_state.aux
+    n_evals, n_iters = int(init_state.n_evals), int(init_state.n_iters)
 
     alpha, gamma, rho_c, shrink_c = 1.0, 2.0, 0.5, 0.5
 
-    def cond_fn(state: NMState):
-        spread_f = state.values[-1] - state.values[0]
-        spread_x = jnp.max(jnp.abs(state.simplex - state.simplex[0:1]))
-        return ((state.n_iters < max_iters)
-                & ((spread_f > ftol) | (spread_x > xtol)))
-
-    def body(state: NMState):
-        simplex, values = state.simplex, state.values
-
-        def recenter_shrink(_):
+    while n_iters < max_iters:
+        vals = np.asarray(values)
+        spread_f = vals[-1] - vals[0]
+        spread_x = float(jnp.max(jnp.abs(simplex - simplex[0:1])))
+        if not (spread_f > ftol or spread_x > xtol):
+            break
+        n_iters += 1
+        if not np.all(np.isfinite(vals)):
             # A vertex went non-finite (sanitized to +inf): pull the whole
             # simplex toward the best vertex instead of reflecting through
             # a poisoned centroid, and re-evaluate everything.
             s = simplex[0:1] + shrink_c * (simplex - simplex[0:1])
-            v, auxs = jax.vmap(ev)(s)
-            s2, v2 = _order(s, v)
-            return NMState(s2, v2, state.n_evals + m + 1, state.n_iters + 1,
-                           _tree_add(state.aux, _tree_sum(auxs)))
+            v, a = _eval_rows(ev, s)
+            simplex, values = _order(s, v)
+            n_evals += m + 1
+            aux = _tree_add(aux, a)
+            continue
 
-        def nm_step(_):
-            centroid = jnp.mean(simplex[:-1], axis=0)
-            worst = simplex[-1]
-            f_best, f_second, f_worst = values[0], values[-2], values[-1]
+        centroid = jnp.mean(simplex[:-1], axis=0)
+        worst = simplex[-1]
+        f_best, f_second, f_worst = vals[0], vals[-2], vals[-1]
 
-            xr = centroid + alpha * (centroid - worst)
-            fr, aux_r = ev(xr)
-            zero_aux = jax.tree.map(jnp.zeros_like, aux_r)
+        xr = centroid + alpha * (centroid - worst)
+        fr, a = ev(xr)
+        n_evals += 1
+        aux = _tree_add(aux, a)
+        new_pt, new_f, accepted = xr, fr, True
+        if float(fr) < f_best:
+            xe = centroid + gamma * (xr - centroid)
+            fe, a = ev(xe)
+            n_evals += 1
+            aux = _tree_add(aux, a)
+            if float(fe) < float(fr):
+                new_pt, new_f = xe, fe
+        elif float(fr) >= f_second:
+            if float(fr) < f_worst:             # outside contraction
+                xc = centroid + rho_c * (xr - centroid)
+                fc, a = ev(xc)
+                accepted = float(fc) <= float(fr)
+            else:                               # inside contraction
+                xc = centroid - rho_c * (centroid - worst)
+                fc, a = ev(xc)
+                accepted = float(fc) < f_worst
+            n_evals += 1
+            aux = _tree_add(aux, a)
+            new_pt, new_f = xc, fc
 
-            def expand(_):
-                xe = centroid + gamma * (xr - centroid)
-                fe, aux_e = ev(xe)
-                better = fe < fr
-                return (jnp.where(better, xe, xr), jnp.where(better, fe, fr),
-                        jnp.asarray(True), jnp.asarray(2), aux_e)
+        if accepted:
+            simplex = simplex.at[-1].set(new_pt)
+            values = values.at[-1].set(new_f)
+        else:
+            # Shrink toward the best vertex, which keeps its value.
+            simplex = simplex[0:1] + shrink_c * (simplex - simplex[0:1])
+            v, a = _eval_rows(ev, simplex[1:])
+            values = jnp.concatenate([values[:1], v])
+            n_evals += m
+            aux = _tree_add(aux, a)
+        simplex, values = _order(simplex, values)
 
-            def reflect_or_contract(_):
-                def accept_reflect(_):
-                    return xr, fr, jnp.asarray(True), jnp.asarray(1), zero_aux
-
-                def contract(_):
-                    def outside(_):
-                        xc = centroid + rho_c * (xr - centroid)
-                        fc, aux_c = ev(xc)
-                        return xc, fc, fc <= fr, jnp.asarray(2), aux_c
-
-                    def inside(_):
-                        xc = centroid - rho_c * (centroid - worst)
-                        fc, aux_c = ev(xc)
-                        return xc, fc, fc < f_worst, jnp.asarray(2), aux_c
-
-                    return lax.cond(fr < f_worst, outside, inside, None)
-
-                return lax.cond(fr < f_second, accept_reflect, contract, None)
-
-            new_pt, new_f, accepted, nev, aux_b = lax.cond(
-                fr < f_best, expand, reflect_or_contract, None)
-
-            def apply_accept(_):
-                s = simplex.at[-1].set(new_pt)
-                v = values.at[-1].set(new_f)
-                return s, v, nev, zero_aux
-
-            def apply_shrink(_):
-                s = simplex[0:1] + shrink_c * (simplex - simplex[0:1])
-                v, auxs = jax.vmap(ev)(s)
-                v = v.at[0].set(values[0])  # best vertex unchanged
-                return s, v, nev + m, _tree_sum(auxs)
-
-            s2, v2, spent, aux_s = lax.cond(accepted, apply_accept,
-                                            apply_shrink, None)
-            s2, v2 = _order(s2, v2)
-            aux_total = _tree_add(_tree_add(state.aux, aux_r),
-                                  _tree_add(aux_b, aux_s))
-            return NMState(s2, v2, state.n_evals + spent + 1,
-                           state.n_iters + 1, aux_total)
-
-        any_bad = ~jnp.all(jnp.isfinite(values))
-        return lax.cond(any_bad, recenter_shrink, nm_step, None)
-
-    final = lax.while_loop(cond_fn, body, state)
-    converged = final.n_iters < max_iters
-    return NMResult(final.simplex[0], final.values[0], final.n_evals,
-                    final.n_iters, converged,
-                    final.aux if has_aux else None, final)
+    final = NMState(simplex, values, jnp.asarray(n_evals),
+                    jnp.asarray(n_iters), aux)
+    return NMResult(simplex[0], values[0], final.n_evals, final.n_iters,
+                    jnp.asarray(n_iters < max_iters),
+                    aux if has_aux else None, final)
 
 
 def multistart_nelder_mead(fn: Callable, x0s, *, checkpoint_dir=None,

@@ -24,6 +24,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .linalg import cho_solve, cholesky
 from .covariance import MaternParams, build_c0, build_sigma
 from .recovery import init_status
 
@@ -98,8 +99,8 @@ def dense_factor(obs_locs, z_obs, params: MaternParams,
     if chol is None:
         sigma = build_sigma(obs_locs, params, representation=representation,
                             nugget=nugget)
-        chol = jnp.linalg.cholesky(sigma)
-    alpha = jax.scipy.linalg.cho_solve((chol, True), z_obs)
+        chol = cholesky(sigma)
+    alpha = cho_solve(chol, z_obs)
     status = init_status(chol.dtype).update_potrf(chol)
     return CokrigeFactor(diag_l=chol, u=None, v=None, ranks=None, alpha=alpha,
                          locs=jnp.asarray(obs_locs), params=params,
@@ -146,8 +147,8 @@ def cokrige(obs_locs, z_obs, pred_locs, params: MaternParams = None,
     else:
         sigma = build_sigma(obs_locs, params, representation=representation,
                             nugget=nugget)
-        chol = jnp.linalg.cholesky(sigma)
-        alpha = jax.scipy.linalg.cho_solve((chol, True), z_obs)
+        chol = cholesky(sigma)
+        alpha = cho_solve(chol, z_obs)
     c0 = build_c0(pred_locs, obs_locs, params, representation=representation)
     # Contract the precomputed Sigma^{-1} Z with all c0 blocks at once.
     return jnp.einsum("lrp,r->lp", c0, alpha)

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .linalg import cholesky
 from .covariance import MaternParams, build_sigma, morton_order
 
 
@@ -47,7 +48,7 @@ def simulate_mgrf(key, locs, params: MaternParams, representation: str = "I",
     n = locs.shape[0]
     p = params.p
     sigma = build_sigma(locs, params, representation=representation, nugget=nugget)
-    chol = jnp.linalg.cholesky(sigma)
+    chol = cholesky(sigma)
     eps = jax.random.normal(key, (nsamples, n * p), dtype=sigma.dtype)
     return eps @ chol.T
 

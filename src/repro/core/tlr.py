@@ -50,6 +50,7 @@ from jax.sharding import NamedSharding
 
 from ..distribution.compress_svd import svd_truncate_batch
 from ..distribution.pair_qr import sharded_recompress
+from .linalg import cholesky, qr, right_svd, solve_lower
 from .covariance import MaternParams, build_sigma, build_sigma_panel
 from .likelihood import LoglikResult
 from .precision import resolve_policy
@@ -131,22 +132,33 @@ def choose_tile_size(m: int, target: int = 0, multiple_of: int = 1) -> int:
     return best
 
 
-def _truncate_svd(u, s, vt, tol: float, kmax: int, scale: float):
-    """Zero-pad a truncated SVD to kmax columns; returns (U, V, rank)."""
-    k = s.shape[0]
-    # threshold in s's dtype: under a mixed policy s is narrow and a wide
-    # traced scale would otherwise promote the comparison (convert churn)
-    keep = s > jnp.asarray(tol * scale, dtype=s.dtype)
-    rank = jnp.minimum(jnp.sum(keep), kmax)
-    idx = jnp.arange(min(k, kmax))
-    mask = (idx < rank)[None, :]
-    uu = u[:, : len(idx)] * jnp.where(mask, s[None, : len(idx)], 0.0)
-    vv = jnp.where(mask, vt[: len(idx), :].T, 0.0)
-    pad = kmax - len(idx)
-    if pad > 0:
-        uu = jnp.pad(uu, ((0, 0), (0, pad)))
-        vv = jnp.pad(vv, ((0, 0), (0, pad)))
-    return uu, vv, rank.astype(jnp.int32)
+def truncate_core(core, tol, kmax: int, scale, left=None, right=None):
+    """Rank <= kmax truncation of ``left @ core @ right^T`` at ``tol *
+    scale``, in the fixed-kmax layout.
+
+    ``core`` is (..., r, r); ``left``/``right`` are orthonormal (..., nb, r)
+    bases (None: the identity, so ``core`` is the tile itself).  Returns
+    (U, V, rank, s): U = left @ core @ V_k carries the singular values, V =
+    right @ V_k, both zero beyond ``rank`` and padded to kmax columns; ``s``
+    is the core's spectrum (breakdown accounting reads it).  ``scale`` may
+    be traced; the threshold is taken in s's dtype, so a wide traced scale
+    does not promote a narrow spectrum (convert churn under mixed policies).
+    """
+    s, v = _right_svd(core)
+    k = min(s.shape[-1], kmax)
+    keep = (s[..., :k] > jnp.asarray(tol * scale, dtype=s.dtype))[..., None, :]
+    vk = v[..., :k]
+    uu = core @ vk
+    if left is not None:
+        uu = left @ uu
+    if right is not None:
+        vk = right @ vk
+    uu = jnp.where(keep, uu, 0.0)
+    vv = jnp.where(keep, vk, 0.0)
+    if k < kmax:
+        pad = [(0, 0)] * (uu.ndim - 1) + [(0, kmax - k)]
+        uu, vv = jnp.pad(uu, pad), jnp.pad(vv, pad)
+    return uu, vv, jnp.sum(keep, axis=(-2, -1)).astype(jnp.int32), s
 
 
 def tlr_compress(sigma, tile_size: int = 0, tol: float = 1e-7,
@@ -322,12 +334,12 @@ def _safe_qr(a):
 
     The recompress concats carry zero-padded rank columns, so R is exactly
     singular and the textbook QR JVP (a triangular solve against R) returns
-    NaN.  The primal is jnp.linalg.qr verbatim; the JVP bumps (near-)zero R
+    NaN.  The primal is ``core.linalg.qr``; the JVP bumps (near-)zero R
     diagonal entries to 1 before the solve — those directions correspond to
     the padded columns, whose downstream contributions the tol*scale rank
     mask zeroes anyway, so the guard only replaces NaN with a finite
     subgradient choice."""
-    q, r = jnp.linalg.qr(a)
+    q, r = qr(a)
     return q, r              # plain tuple: custom_jvp needs one pytree shape
 
 
@@ -356,67 +368,54 @@ def _safe_qr_jvp(primals, tangents):
 
 
 @jax.custom_jvp
-def _core_svd(core):
-    """SVD of the square recompress core with degenerate-gap-safe
-    derivatives.
+def _right_svd(a):
+    """Singular values (descending) and right singular vectors of ``a``
+    (..., m, n) with degenerate-gap-safe derivatives.
 
-    The core's zero-padded rank columns give it *exactly repeated* zero
-    singular values, and the textbook SVD JVP divides by s_j^2 - s_i^2 —
-    NaN gradients for every traced-parameter MLE that differentiates
-    through the factorization.  The primal is jnp.linalg.svd verbatim
-    (full_matrices=False — identical for a square core); the custom JVP
-    zeroes the 1/(s_j^2 - s_i^2) terms inside (near-)degenerate blocks.
-    Those components are exactly the ones the tol*scale rank mask zeroes
-    downstream, so the product derivative the likelihood consumes is
-    unaffected — the guard only replaces NaN with a finite subgradient
-    choice."""
-    u, s, vt = jnp.linalg.svd(core, full_matrices=False)
-    return u, s, vt          # plain tuple: custom_jvp needs one pytree shape
+    The primal is ``core.linalg.right_svd`` (Jacobi on TPU).
+
+    Recompress cores carry zero-padded rank columns, so they have exactly
+    repeated zero singular values and the textbook derivative divides by
+    s_j^2 - s_i^2.  The JVP (the eigen-perturbation of a^T a) zeroes those
+    terms inside (near-)degenerate blocks; they are the components the
+    tol*scale rank mask drops downstream, so the guard only replaces NaN
+    with a finite subgradient choice."""
+    return right_svd(a)
 
 
-@_core_svd.defjvp
-def _core_svd_jvp(primals, tangents):
+@_right_svd.defjvp
+def _right_svd_jvp(primals, tangents):
     (a,), (da,) = primals, tangents
-    u, s, vt = _core_svd(a)
-    v = jnp.swapaxes(vt, -1, -2)
-    dp = jnp.swapaxes(u, -1, -2) @ da @ v               # (..., n, n)
-    ds = jnp.diagonal(dp, axis1=-2, axis2=-1)
+    s, v = _right_svd(a)
+    w = jnp.swapaxes(a @ v, -1, -2) @ (da @ v)
+    dg = w + jnp.swapaxes(w, -1, -2)                    # V^T d(A^T A) V
     s2 = s * s
+    lim = 1e-40 + 1e-12 * jnp.max(s2, axis=-1, keepdims=True)
+    pos = s2 > lim
+    ds = jnp.where(pos, jnp.diagonal(dg, axis1=-2, axis2=-1), 0.0) / (
+        2.0 * jnp.where(pos, s, 1.0))
     gap = s2[..., None, :] - s2[..., :, None]           # gap[i,j] = s_j^2-s_i^2
-    lim = 1e-40 + 1e-12 * jnp.max(s2, axis=-1, keepdims=True)[..., None]
-    safe = jnp.abs(gap) > lim
+    safe = jnp.abs(gap) > lim[..., None]
     f = jnp.where(safe, 1.0, 0.0) / jnp.where(safe, gap, 1.0)
-    dpt = jnp.swapaxes(dp, -1, -2)
-    du = u @ (f * (dp * s[..., None, :] + s[..., :, None] * dpt))
-    dv = v @ (f * (s[..., :, None] * dp + dpt * s[..., None, :]))
-    return (u, s, vt), (du, ds, jnp.swapaxes(dv, -1, -2))
+    return (s, v), (ds, v @ (f * dg))
 
 
 def _recompress_parts(u1, v1, u2, v2, tol, scale):
     """(B..., nb, k) pairs -> recompressed sum with rank <= kmax, batched.
 
-    QR(U')·QR(V') then SVD of the small core.  Returns (U, V, ranks, cs)
-    where ranks counts the singular values kept (int32, shape B...) and cs
-    is the raw singular-value spectrum (for breakdown accounting — a NaN
-    input tile surfaces here as non-finite singular values).
+    QR(U')·QR(V') then the truncated SVD of the small core
+    (``truncate_core``).  Returns (U, V, ranks, cs) where ranks counts the
+    singular values kept (int32, shape B...) and cs is the raw
+    singular-value spectrum (for breakdown accounting — a NaN input tile
+    surfaces here as non-finite singular values).
     """
     kmax = u1.shape[-1]
     ucat = jnp.concatenate([u1, u2], axis=-1)       # (..., nb, 2k)
     vcat = jnp.concatenate([v1, v2], axis=-1)
-    qu, ru = _safe_qr(ucat)
-    qv, rv = _safe_qr(vcat)
+    # one batched QR for both sides: a single QR in the compiled program
+    (qu, qv), (ru, rv) = _safe_qr(jnp.stack([ucat, vcat]))
     core = ru @ jnp.swapaxes(rv, -1, -2)
-    cu, cs, cvt = _core_svd(core)
-    # cs is sorted descending, so thresholding the first kmax values gives
-    # min(#kept, kmax) — the same rank the unbatched form reports.
-    # Threshold in cs's dtype: a wide traced scale must not promote the
-    # narrow recompress spectrum (convert churn inside the panel loop).
-    mask = (cs[..., :kmax] > jnp.asarray(tol * scale, dtype=cs.dtype))
-    s_m = jnp.where(mask, cs[..., :kmax], 0.0)
-    unew = jnp.einsum("...nk,...k->...nk", qu @ cu[..., :kmax], s_m)
-    vnew = qv @ jnp.swapaxes(cvt[..., :kmax, :], -1, -2)
-    vnew = jnp.where(mask[..., None, :], vnew, 0.0)
-    return unew, vnew, jnp.sum(mask, axis=-1).astype(jnp.int32), cs
+    return truncate_core(core, tol, kmax, scale, left=qu, right=qv)
 
 
 def _batched_recompress(u1, v1, u2, v2, tol, scale):
@@ -457,6 +456,16 @@ class TLRCholesky(NamedTuple):
     status: FactorStatus | None = None  # breakdown accounting (if tracked)
 
 
+def panel_trsm(lkk, vk):
+    """L_kk^{-1} V_ik for a whole (T, nb, kmax) panel column as one
+    multi-RHS solve against the wide diagonal factor; the result is cast
+    back to the panel's storage dtype."""
+    T, nb, k = vk.shape
+    rhs = jnp.moveaxis(vk.astype(lkk.dtype), 0, 1).reshape(nb, T * k)
+    x = solve_lower(lkk, rhs).reshape(nb, T, k)
+    return jnp.moveaxis(x, 1, 0).astype(vk.dtype)
+
+
 def tlr_panel_body(k, diag, u, v, ranks, status=None, *, tol, scale,
                    pairs=None, mesh=None, dspec=None, uvspec=None):
     """One right-looking panel step k on rank-padded (kmax) trailing blocks.
@@ -491,7 +500,7 @@ def tlr_panel_body(k, diag, u, v, ranks, status=None, *, tol, scale,
     # ---- POTRF on tile (k, k): replicated small factorization.
     dkk = lax.dynamic_index_in_dim(diag, k, 0, keepdims=False)
     # spmdlint: ignore[R1] one (nb, nb) panel-head POTRF replicated on purpose: every shard needs L_kk immediately and nb^2 is tiny next to the pair batch
-    lkk = jnp.linalg.cholesky(dkk)
+    lkk = cholesky(dkk)
     if status is not None:
         status = status.update_potrf(lkk)
     row_is_k = (rows == k)[:, None, None]
@@ -500,9 +509,7 @@ def tlr_panel_body(k, diag, u, v, ranks, status=None, *, tol, scale,
     # TRSM widening boundary: the solve runs against the wide diagonal
     # factor and the result is stored back at the (possibly narrow) U/V
     # storage dtype.  Uniform-dtype policies make both casts no-ops.
-    vk_solved = jax.vmap(lambda b: lax.linalg.triangular_solve(
-        lkk, b, left_side=True, lower=True))(
-        vk.astype(lkk.dtype)).astype(vk.dtype)
+    vk_solved = panel_trsm(lkk, vk)
     below = (rows > k)[:, None, None]
     vk = jnp.where(below, vk_solved, vk)
     v = lax.dynamic_update_index_in_dim(v, vk, k, 1)
@@ -625,7 +632,7 @@ def tlr_panel_body_bc(k, diag, up, vp, ranks, status=None, *, layout, tol,
     # ---- POTRF on tile (k, k): replicated small factorization.
     dkk = lax.dynamic_index_in_dim(diag, k, 0, keepdims=False)
     # spmdlint: ignore[R1] one (nb, nb) panel-head POTRF replicated on purpose: every shard needs L_kk immediately and nb^2 is tiny next to the pair batch
-    lkk = jnp.linalg.cholesky(dkk)
+    lkk = cholesky(dkk)
     if status is not None:
         status = status.update_potrf(lkk)
     row_is_k = (rows == k)[:, None, None]
@@ -637,9 +644,7 @@ def tlr_panel_body_bc(k, diag, up, vp, ranks, status=None, *, layout, tol,
     uk = up.at[pcol].get(mode="fill", fill_value=0.0)
     # ---- TRSM on panel column k (V only; U untouched — §5.3).
     # TRSM widening boundary: solve wide against L_kk, store back narrow.
-    vk_solved = jax.vmap(lambda b: lax.linalg.triangular_solve(
-        lkk, b, left_side=True, lower=True))(
-        vk.astype(lkk.dtype)).astype(vk.dtype)
+    vk_solved = panel_trsm(lkk, vk)
     vk = jnp.where(below, vk_solved, vk)
     vp = vp.at[pcol].set(vk, mode="drop")  # OOB slots (i <= k) are dropped
     # ---- SYRK onto trailing diagonal tiles i > k: D_i -= U (V^T V) U^T.
@@ -712,7 +717,7 @@ def tlr_cholesky(t: TLRMatrix, tol: float = 1e-9, scale: float = 1.0,
             diag, u, v, ranks, status = out
         else:
             diag, u, v, ranks = out
-    lkk = jnp.linalg.cholesky(diag[T - 1])  # last column: POTRF only
+    lkk = cholesky(diag[T - 1])  # last column: POTRF only
     if track_status:
         status = status.update_potrf(lkk)
     diag = diag.at[T - 1].set(lkk)
@@ -734,8 +739,7 @@ def solve_lower_grid(diag_l, u, v, z) -> jax.Array:
         z, out = carry
         lkk = lax.dynamic_index_in_dim(diag_l, k, 0, keepdims=False)
         zk = lax.dynamic_index_in_dim(z, k, 0, keepdims=False)
-        ak = lax.linalg.triangular_solve(lkk, zk[:, None], left_side=True,
-                                         lower=True)[:, 0]
+        ak = solve_lower(lkk, zk)
         out = lax.dynamic_update_index_in_dim(out, ak, k, 0)
         # z_i -= U_ik (V_ik^T a_k) for i > k  (masked batched).
         uk = lax.dynamic_index_in_dim(u, k, 1, keepdims=False)

@@ -74,13 +74,8 @@ def compressed_psum(tree, mesh, axis_name: str = "pod", errors=None):
             jax.tree.map(
                 lambda g, er: compressed_psum_leaf(g, axis_name, er)[1], t, e)
 
-    kwargs = dict(mesh=mesh, in_specs=(flat_specs, flat_specs),
-                  out_specs=(flat_specs, flat_specs))
-    if hasattr(jax, "shard_map"):                     # jax >= 0.7 public API
-        fn = jax.shard_map(inner, check_vma=False, **kwargs)
-    else:
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(inner, check_rep=False, **kwargs)
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=(flat_specs, flat_specs),
+                       out_specs=(flat_specs, flat_specs), check_vma=False)
     return fn(tree, errors)
 
 

@@ -34,8 +34,8 @@ from __future__ import annotations
 import math
 import warnings
 
+import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["pair_shard_count", "pad_leading", "warn_fallback_once",
@@ -129,19 +129,18 @@ def sharded_recompress(up, vp, du, dv, tol, scale, *, mesh=None, axes=None,
                                                           tol, sc)
             return u_l, v_l, r_l, bad[None]   # (1,) per shard -> (S,) global
 
-        fn = shard_map(local, mesh,
-                       in_specs=(spec, spec, spec, spec, P()),
-                       out_specs=(spec, spec, P(axes), P(axes)),
-                       check_rep=False)
+        fn = jax.shard_map(local, mesh=mesh,
+                           in_specs=(spec, spec, spec, spec, P()),
+                           out_specs=(spec, spec, P(axes), P(axes)),
+                           check_vma=False)
         un, vn, rn, bad = fn(up, vp, du, dv, scale)
         return un[:length], vn[:length], rn[:length], jnp.sum(bad)
 
     def local(u1, v1, u2, v2, sc):
         return _batched_recompress(u1, v1, u2, v2, tol, sc)
 
-    fn = shard_map(local, mesh,
-                   in_specs=(spec, spec, spec, spec, P()),
-                   out_specs=(spec, spec, P(axes)),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(spec, spec, spec, spec, P()),
+                       out_specs=(spec, spec, P(axes)), check_vma=False)
     un, vn, rn = fn(up, vp, du, dv, scale)
     return un[:length], vn[:length], rn[:length]

@@ -19,7 +19,6 @@ panel into VMEM plus two SMEM scalars (1/a, amp) and writes a (bn, bm) tile.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -29,13 +28,17 @@ _SUPPORTED_NU = (0.5, 1.5, 2.5)
 
 
 def _default_interpret() -> bool:
-    """Resolve ``interpret=None``: compiled Mosaic on a real TPU backend,
-    interpreter everywhere else (CPU tests / dry-run hosts).  The
-    REPRO_PALLAS_INTERPRET env var (0/1) overrides the auto-detection."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env.strip().lower() not in ("0", "false", "no")
+    """Resolve ``interpret=None``: compiled Mosaic on a TPU backend, the
+    interpreter everywhere else (CPU tests, compile rehearsals)."""
     return jax.default_backend() != "tpu"
+
+
+def compiles_for(dtype, interpret: bool | None = None) -> bool:
+    """Whether ``matern_tile`` runs at ``dtype``: the interpreter takes any
+    float; Mosaic on TPU has no 64-bit element types."""
+    if interpret is None:
+        interpret = _default_interpret()
+    return interpret or jnp.dtype(dtype).itemsize <= 4
 
 
 def _matern_halfint_body(u, nu: float):
@@ -88,8 +91,8 @@ def matern_tile(locs_a, locs_b, inv_range, amp, *, nu: float,
     nearest divisor of n / m, so callers may hand arbitrary panel shapes
     (the TLR strict-lower panels are (T-1-j)*nbl tall).  nu must be a static
     half-integer in {0.5, 1.5, 2.5}.  ``interpret=None`` auto-selects:
-    compiled Mosaic on TPU, interpreter elsewhere (override with
-    REPRO_PALLAS_INTERPRET).
+    compiled Mosaic on TPU, interpreter elsewhere.  Compiled, the kernel
+    takes float32 or narrower locations (``compiles_for``).
     """
     if nu not in _SUPPORTED_NU:
         raise ValueError(f"kernel supports nu in {_SUPPORTED_NU}; general nu "
@@ -99,6 +102,9 @@ def matern_tile(locs_a, locs_b, inv_range, amp, *, nu: float,
     n, m = locs_a.shape[0], locs_b.shape[0]
     bn, bm = _fit_block(n, block_n), _fit_block(m, block_m)
     dtype = jnp.result_type(locs_a.dtype, locs_b.dtype)
+    if not compiles_for(dtype, interpret):
+        raise TypeError(f"matern_tile: Mosaic has no {dtype} lowering on TPU; "
+                        "pass float32 locations or generate with gen='xla'")
     scalars = jnp.stack([jnp.asarray(inv_range, dtype),
                          jnp.asarray(amp, dtype)]).reshape(1, 2)
 
@@ -107,10 +113,12 @@ def matern_tile(locs_a, locs_b, inv_range, amp, *, nu: float,
         functools.partial(_matern_tile_kernel, nu=nu),
         out_shape=jax.ShapeDtypeStruct((n, m), dtype),
         grid=grid,
+        # Index maps return int32 zeros: a bare 0 is int64 under x64, which
+        # Mosaic cannot lower ("failed to legalize func.return").
         in_specs=[
-            pl.BlockSpec((1, 2), lambda i, j: (0, 0)),          # scalars
-            pl.BlockSpec((bn, 2), lambda i, j: (i, 0)),         # row panel
-            pl.BlockSpec((bm, 2), lambda i, j: (j, 0)),         # col panel
+            pl.BlockSpec((1, 2), lambda i, j: (i * 0, j * 0)),  # scalars
+            pl.BlockSpec((bn, 2), lambda i, j: (i, j * 0)),     # row panel
+            pl.BlockSpec((bm, 2), lambda i, j: (j, i * 0)),     # col panel
         ],
         out_specs=pl.BlockSpec((bn, bm), lambda i, j: (i, j)),
         interpret=interpret,

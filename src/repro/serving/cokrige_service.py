@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..core.linalg import solve_lower
 from ..core.covariance import (MaternParams, build_c0_panels,
                                build_sigma_panel, cross_cov_at_zero)
 from ..core.dist_tlr import (dist_compress_tiles, dist_tlr_cholesky_pairs,
@@ -193,8 +194,7 @@ def _predict_core(factor: CokrigeFactor, pred_locs, *, interval: float,
         c0 = build_sigma_panel(factor.locs, pred_locs, params,
                                d_spatial=factor.d_spatial,
                                gen=gen)                       # (m, B*p)
-        w = jax.lax.linalg.triangular_solve(
-            factor.diag_l, c0, left_side=True, lower=True)
+        w = solve_lower(factor.diag_l, c0)
     else:
         T, nb = factor.diag_l.shape[0], factor.diag_l.shape[1]
         layout = pair_layout(T, factor.n_shards)

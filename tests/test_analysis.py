@@ -115,7 +115,8 @@ def test_r1_replicated_qr_pair_multidevice():
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
         from repro.analysis import lint_lowerable
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((8,), ("data",))
         fn, specs, kw = mod.make_bad(mesh)
         rep = lint_lowerable(fn, specs, mesh=mesh, **kw)
         bad = [f for f in rep.findings if f.rule == "R1" and not f.suppressed]

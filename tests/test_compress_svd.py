@@ -23,6 +23,7 @@ from repro.distribution.block_cyclic import (column_owner_tables, pair_layout,
                                              pair_shards)
 from repro.distribution.compress_svd import (sharded_truncate_svd,
                                              svd_truncate_batch)
+from repro.launch.mesh import auto_mesh
 
 
 def _tile_batch(b=11, nb=16, seed=0):
@@ -40,7 +41,7 @@ def test_sharded_truncate_svd_fallback_and_mesh():
     got = sharded_truncate_svd(tiles, 1e-6, 8, 1.0)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=0.0)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = auto_mesh((1,), ("data",))
     got_m = sharded_truncate_svd(tiles, 1e-6, 8, 1.0, mesh=mesh,
                                  axes=("data",))
     assert got_m[0].shape == want[0].shape        # pads stripped
@@ -91,7 +92,7 @@ def test_owned_slot_compress_matches_replicated_and_dense():
     tlr_compress — values AND ranks (the ISSUE-5 single-device
     acceptance)."""
     locs, params = _setup_m128()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     lay = pair_layout(4, pair_shards(mesh))
     kw = dict(tile_size=32, tol=1e-9, max_rank=16, nugget=1e-6)
     sh = dist_compress_tiles(locs, params, mesh=mesh, layout=lay, **kw)
@@ -118,7 +119,7 @@ def test_col_block_owned_slot_compress_matches():
     """col_block > 1 (super-panel column groups) through the owned-slot
     path scatters the same tiles as col_block=1."""
     locs, params = _setup_m128()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = auto_mesh((1,), ("data",))
     lay = pair_layout(4, pair_shards(mesh, ("data",)))
     kw = dict(tile_size=32, tol=1e-7, max_rank=16, nugget=1e-8, mesh=mesh,
               row_axes=("data",), layout=lay)
@@ -138,7 +139,7 @@ def test_layout_mesh_shard_mismatch_warns_and_falls_back():
     from repro.distribution import pair_qr
 
     locs, params = _setup_m128()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     lay3 = pair_layout(4, 3)                 # mesh spans 1 shard, not 3
     kw = dict(tile_size=32, tol=1e-7, max_rank=16, nugget=1e-8)
     want = dist_compress_tiles(locs, params, mesh=None, layout=lay3, **kw)
@@ -167,6 +168,7 @@ sys.path.insert(0, {src!r})
 import jax
 import jax.numpy as jnp
 import numpy as np
+from repro.launch.mesh import auto_mesh
 """
 
 
@@ -197,7 +199,7 @@ def test_owned_slot_compress_shard_counts_subprocess():
                                     dtype=jnp.float32)
     kw = dict(tile_size=32, tol=1e-7, max_rank=16, nugget=1e-6)
     for S in (1, 2, 4):
-        mesh = jax.make_mesh((S,), ("data",))
+        mesh = auto_mesh((S,), ("data",))
         lay = pair_layout(4, S)
         sh = dist_compress_tiles(locs, params, mesh=mesh, layout=lay, **kw)
         repl = dist_compress_tiles(locs, params, mesh=None, layout=lay, **kw)
@@ -224,7 +226,7 @@ def test_compress_sharded_pipeline_multidevice():
     from repro.core.dist_tlr import dist_tlr_loglik
     from repro.core.simulate import grid_locations, simulate_mgrf
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     locs = grid_locations(16, jitter=0.2, seed=0)      # 256 locs, m = 512
     locs = np.asarray(locs)[morton_order(locs)].astype(np.float32)
     params = MaternParams.bivariate(a=0.09, nu11=0.5, nu22=1.0, beta=0.5,

@@ -60,6 +60,20 @@ def test_sigma_matches_oracle(rep):
     np.testing.assert_allclose(got, want, rtol=1e-7, atol=1e-10)
 
 
+def test_sigma_column_panels_match_oracle(monkeypatch):
+    """The TPU assembly: column panels of _PANEL_LOCS locations under a
+    fori_loop, nugget added on the diagonal."""
+    from repro.core import linalg
+
+    monkeypatch.setattr(linalg, "_on_tpu", lambda: True)
+    monkeypatch.setattr(cov, "_PANEL_LOCS", 8)
+    locs = uniform_locations(24, seed=1)
+    params = _params()
+    got = np.asarray(cov.build_sigma(locs, params, nugget=1e-3))
+    want = _sigma_oracle(locs, params, "I") + 1e-3 * np.eye(48)
+    np.testing.assert_allclose(got, want, rtol=1e-7, atol=1e-10)
+
+
 def test_representations_are_permutations():
     locs = uniform_locations(17, seed=2)
     params = _params()

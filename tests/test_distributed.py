@@ -433,6 +433,7 @@ sys.path.insert(0, {src!r})
 import jax
 import jax.numpy as jnp
 import numpy as np
+from repro.launch.mesh import auto_mesh
 """
 
 
@@ -451,7 +452,7 @@ def test_compressed_psum_multidevice():
     out = _run_subprocess("""
     from jax.sharding import PartitionSpec as P
     from repro.distribution.compression import compressed_psum
-    mesh = jax.make_mesh((8,), ("pod",))
+    mesh = auto_mesh((8,), ("pod",))
     rng = np.random.default_rng(0)
     g = {"a": jnp.asarray(rng.normal(size=(64, 32)), jnp.float32),
          "b": jnp.asarray(rng.normal(size=(257,)), jnp.float32)}
@@ -479,7 +480,7 @@ def test_train_step_shards_multidevice():
     from repro.dataio.tokens import SyntheticTokens
 
     cfg = get_arch("qwen3-4b").reduced()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     tcfg = TrainConfig(remat=False)
     step = make_train_step(cfg, mesh, tcfg)
     params = shard_params(init_model(jax.random.PRNGKey(0), cfg), cfg, mesh)
@@ -513,7 +514,7 @@ def test_elastic_checkpoint_restore_across_topologies(tmp_path):
     from repro.checkpointing.checkpoint import restore_checkpoint
     from repro.distribution.sharding import param_specs, shardings_of
     cfg = get_arch("yi-6b").reduced()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     target = dict(params=init_model(jax.random.PRNGKey(0), cfg))
     sh = dict(params=shardings_of(param_specs(cfg), mesh))
     restored, manifest = restore_checkpoint({str(tmp_path)!r}, target,
@@ -541,7 +542,7 @@ def test_dist_tlr_pipeline_multidevice():
                                      dist_tlr_pipeline_lowerable)
     from repro.core.simulate import grid_locations, simulate_mgrf
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     locs = grid_locations(16, jitter=0.2, seed=0)      # 256 locs, m = 512
     locs = np.asarray(locs)[morton_order(locs)]
     params = MaternParams.bivariate(a=0.09, nu11=0.5, nu22=1.0, beta=0.5,
@@ -613,7 +614,7 @@ def test_dist_cholesky_lowerable_donates_in_place():
     from repro.analysis import lint_lowerable
 
     m, panel = 256, 64
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = auto_mesh((8, 1), ("data", "model"))
     fn, specs = dist_cholesky_lowerable(m, panel=panel, mesh=mesh,
                                         dtype=jnp.float32)
     sh = (NamedSharding(mesh, P("data", "model")),)

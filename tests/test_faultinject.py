@@ -226,6 +226,7 @@ import jax
 jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 import numpy as np
+from repro.launch.mesh import auto_mesh
 """
 
 
@@ -256,7 +257,7 @@ def test_fault_8device_subprocess():
                                                fit_factor, predict_batch)
     from repro.testing import corrupt_diag_tile
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     locs = np.asarray(grid_locations(16, jitter=0.2, seed=0))  # m = 512
     locs[-4:] = locs[:4]      # 4 colliding sensors: Sigma singular at nugget 0
     locs = jnp.asarray(locs[morton_order(locs)])

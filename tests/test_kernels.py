@@ -66,6 +66,25 @@ def test_matern_tile_vs_sigma_build():
     np.testing.assert_allclose(got, want, rtol=1e-9, atol=1e-12)
 
 
+def test_matern_tile_refuses_f64_when_compiled():
+    """Mosaic has no 64-bit lowering: compiled mode refuses f64 locations
+    up front instead of failing inside the TPU compiler, and the panel
+    builder then takes the XLA generator."""
+    from repro.core.covariance import MaternParams, build_sigma_panel
+    from repro.kernels.matern_tile import compiles_for
+    locs = jnp.asarray(np.random.default_rng(0).uniform(size=(64, 2)))
+    with pytest.raises(TypeError, match="float64"):
+        matern_tile(locs, locs, 1.0, 1.0, nu=0.5, interpret=False)
+    assert not compiles_for(jnp.float64, interpret=False)
+    assert compiles_for(jnp.float32, interpret=False)
+    assert compiles_for(jnp.float64, interpret=True)
+    params = MaternParams.univariate(a=0.15, nu=0.5)
+    got = build_sigma_panel(locs, locs, params, gen="pallas")
+    want = build_sigma_panel(locs, locs, params, gen="xla")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-12, atol=1e-14)
+
+
 # ---------------------------------------------------------------------------
 # tlr_mm
 # ---------------------------------------------------------------------------

@@ -23,6 +23,17 @@ def test_kv_matches_scipy(nu):
     np.testing.assert_allclose(got, want, rtol=5e-9)
 
 
+@pytest.mark.parametrize("x", [0.05, 0.2, 0.5])
+def test_kv_cf2_within_f32_exponent_range(x):
+    """CF2 run long (hundreds of terms at x < 2) in f32, whose exponent
+    range TPU's emulated f64 shares: the carry must not overflow into
+    inf * 0 = NaN."""
+    got = matern._kv_steed_cf2(jnp.float32(0.3),
+                               jnp.asarray([x], jnp.float32), max_iter=2000)
+    np.testing.assert_allclose(np.asarray(got[0]), sps.kv(0.3, [x]),
+                               rtol=1e-5)
+
+
 def test_kv_half_integer_closed_forms():
     for nu in (0.5, 1.5, 2.5):
         got = np.asarray(matern.kv_half_integer(nu, jnp.asarray(XS)))

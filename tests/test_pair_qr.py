@@ -20,6 +20,7 @@ from repro.core.dist_tlr import dist_tlr_cholesky
 from repro.core.simulate import grid_locations
 from repro.core.tlr import _batched_recompress
 from repro.distribution.pair_qr import pair_shard_count, sharded_recompress
+from repro.launch.mesh import auto_mesh
 
 
 def _pair_batch(length, nb=16, kmax=4, n_pad=3, seed=0):
@@ -48,7 +49,7 @@ def test_shard_map_single_device_mesh_matches():
     """A 1-device mesh genuinely routes through shard_map (not the
     fallback) and reproduces the replicated batch, pad slots included."""
     up, vp, du, dv = _pair_batch(12)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = auto_mesh((1,), ("data",))
     want = _batched_recompress(up, vp, du, dv, 1e-7, 1.0)
     got = sharded_recompress(up, vp, du, dv, 1e-7, 1.0, mesh=mesh,
                              axes=("data",))
@@ -128,7 +129,7 @@ def test_sharded_factorization_matches_masked_and_dense_m512():
     block-cyclic factorization == masked full-grid == dense Cholesky,
     values AND ranks (the ISSUE-4 single-device acceptance)."""
     t, sigma = _tiles_m512()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = auto_mesh((1,), ("data",))
     ref = dist_tlr_cholesky(t.diag, t.u, t.v, t.ranks, tol=1e-12, scale=1.0)
     got = dist_tlr_cholesky(t.diag, t.u, t.v, t.ranks, tol=1e-12, scale=1.0,
                             mesh=mesh, block_cyclic=True)
@@ -160,7 +161,7 @@ def test_sharded_factorization_super_panels_matches():
     """The two-level (shrinking pair layout) variant threads shard_axes
     through every super-step."""
     t, _ = _tiles_m512()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = auto_mesh((1,), ("data",))
     one = dist_tlr_cholesky(t.diag, t.u, t.v, t.ranks, tol=1e-12, scale=1.0,
                             mesh=mesh, block_cyclic=True)
     two = dist_tlr_cholesky(t.diag, t.u, t.v, t.ranks, tol=1e-12, scale=1.0,
@@ -187,6 +188,7 @@ sys.path.insert(0, {src!r})
 import jax
 import jax.numpy as jnp
 import numpy as np
+from repro.launch.mesh import auto_mesh
 """
 
 
@@ -214,7 +216,7 @@ def test_sharded_recompress_shard_counts_subprocess():
             for _ in range(4))
         up = up.at[-3:].set(0.0); vp = vp.at[-3:].set(0.0)
         du = du.at[-3:].set(0.0); dv = dv.at[-3:].set(0.0)
-        mesh = jax.make_mesh((S,), ("data",))
+        mesh = auto_mesh((S,), ("data",))
         want = _batched_recompress(up, vp, du, dv, 1e-6, 1.0)
         got = sharded_recompress(up, vp, du, dv, 1e-6, 1.0, mesh=mesh,
                                  axes=("data",))
@@ -249,7 +251,7 @@ def test_sharded_factorization_multidevice():
     from repro.core.dist_tlr import dist_compress_tiles, dist_tlr_cholesky
     from repro.core.simulate import grid_locations
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     locs = grid_locations(16, jitter=0.2, seed=0)      # 256 locs, m = 512
     locs = np.asarray(locs)[morton_order(locs)]
     params = MaternParams.bivariate(a=0.09, nu11=0.5, nu22=1.0, beta=0.5,

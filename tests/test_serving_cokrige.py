@@ -243,6 +243,7 @@ import jax
 jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 import numpy as np
+from repro.launch.mesh import auto_mesh
 """
 
 
@@ -267,7 +268,7 @@ def test_serving_8device_subprocess():
     from repro.serving.cokrige_service import (CokrigeServeConfig,
                                                make_cokrige_serve_fns)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     locs = grid_locations(16, jitter=0.2, seed=0)      # 256 locs, m = 512
     locs = np.asarray(locs)[morton_order(locs)]
     params = MaternParams.bivariate(a=0.09, nu11=0.5, nu22=1.0, beta=0.5)

@@ -35,6 +35,21 @@ def test_build_sigma_panel_matches_dense_slices():
                                    rtol=1e-12, atol=1e-14)
 
 
+@pytest.mark.parametrize("row_block", [0, 16])
+def test_build_sigma_column_matches_dense_slice(row_block):
+    """A tile column in one piece and in row chunks (the one-device
+    compression loop), at a traced column index."""
+    from repro.core.covariance import build_sigma_column
+
+    locs = _locs(8)
+    params = MaternParams.bivariate(a=0.09, nu11=0.5, nu22=1.3, beta=0.5)
+    sigma = np.asarray(build_sigma(locs, params))
+    col = jax.jit(lambda j: build_sigma_column(locs, j, 16, params,
+                                               row_block=row_block))(2)
+    np.testing.assert_allclose(np.asarray(col), sigma[:, 64:96],
+                               rtol=1e-12, atol=1e-14)
+
+
 # nu pairs whose pairwise orders (nu_i + nu_j)/2 are all half-integers are
 # Pallas-eligible; (0.5, 1.0) forces the general-nu XLA fallback for nu_12.
 @pytest.mark.parametrize("gen", ["pallas", "xla"])
