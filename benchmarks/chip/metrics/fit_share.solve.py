@@ -1,0 +1,7 @@
+"""Share of the fit window's device leaf time in the program's
+``repro.solve`` scope (%); see ``chipbench/scopes.py``."""
+from chipbench.scopes import fit_share
+
+
+def read(r):
+    return fit_share(r, "solve")

@@ -132,6 +132,7 @@ def _pair_correlations(dists, params: MaternParams, d_spatial: int = 2):
 _PANEL_LOCS = 512
 
 
+@jax.named_scope("repro.gen")
 def build_sigma(locs, params: MaternParams, representation: str = "I",
                 d_spatial: int = 2, nugget: float | None = None, dists=None):
     """Assemble Sigma(theta) of shape (p*n, p*n).
@@ -301,6 +302,7 @@ def build_c0(pred_locs, obs_locs, params: MaternParams, representation: str = "I
     return c0
 
 
+@jax.named_scope("repro.gen")
 def build_c0_panels(obs_locs, pred_locs, params: MaternParams, *, nbl: int,
                     d_spatial: int = 2, gen: str = "xla"):
     """Prediction cross-covariance in *tile-panel* form, generator-direct.

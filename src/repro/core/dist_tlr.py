@@ -163,6 +163,7 @@ def _pair_specs(mesh, row_axes):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("repro.compress")
 def dist_compress_tiles(locs, params, *, tile_size: int = 0, tol: float = 1e-7,
                         max_rank: int = 0, nugget: float = 0.0,
                         gen: str = "pallas", d_spatial: int = 2, scale=None,
@@ -257,9 +258,11 @@ def dist_compress_tiles(locs, params, *, tile_size: int = 0, tol: float = 1e-7,
 
     def body(g, carry):
         diag, u, v, ranks = carry
-        panel = build_sigma_column(locs, g, cb * nbl, params,
-                                   d_spatial=d_spatial, gen=gen, block=nb,
-                                   row_block=nbl if mesh is None else 0)
+        with jax.named_scope("repro.gen"):
+            panel = build_sigma_column(locs, g, cb * nbl, params,
+                                       d_spatial=d_spatial, gen=gen,
+                                       block=nb,
+                                       row_block=nbl if mesh is None else 0)
         panel = _constrain(panel, mesh, P(row, "model"))
         tiles = panel.reshape(T, nb, cb, nb).transpose(2, 0, 1, 3)
         # SVD input down-cast to U/V storage dtype; diagonal tiles below
@@ -370,8 +373,9 @@ def _compress_tiles_pair_sharded(locs, params, *, layout: PairLayout, nb, nbl,
             cidx = (ci[:, None] * nbl + blk_off[None, :]).reshape(-1)
             row_locs = locs_f.at[ridx].get(mode="fill", fill_value=0.0)
             col_locs = locs_f.at[cidx].get(mode="fill", fill_value=0.0)
-            tiles = gen_tile(row_locs.reshape(sb, nbl, -1),
-                             col_locs.reshape(sb, nbl, -1))
+            with jax.named_scope("repro.gen"):
+                tiles = gen_tile(row_locs.reshape(sb, nbl, -1),
+                                 col_locs.reshape(sb, nbl, -1))
             tiles = tiles.astype(u_l.dtype)  # (sb, nb, nb), owned pairs only
             Ug, Vg, Rg = svd_truncate_batch(tiles, tol, kmax, sc)
             tgt = g * sb + jnp.arange(sb, dtype=ri.dtype)
@@ -405,8 +409,9 @@ def _compress_tiles_pair_sharded(locs, params, *, layout: PairLayout, nb, nbl,
         for c in range(cb):
             j = g * cb + c
             pj = lax.dynamic_slice_in_dim(locs, j * nbl, nbl, axis=0)
-            dj = build_sigma_panel(pj, pj, params, d_spatial=d_spatial,
-                                   gen=gen, block=nb).astype(dtype)
+            with jax.named_scope("repro.gen"):
+                dj = build_sigma_panel(pj, pj, params, d_spatial=d_spatial,
+                                       gen=gen, block=nb).astype(dtype)
             dj = apply_nugget(dj, nugget, dtype)
             diag = lax.dynamic_update_index_in_dim(diag, dj, j, 0)
         return _constrain(diag, mesh, dspec)
@@ -422,6 +427,7 @@ def _compress_tiles_pair_sharded(locs, params, *, layout: PairLayout, nb, nbl,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("repro.factorize")
 def dist_tlr_cholesky(diag, u, v, ranks=None, *, tol: float = 1e-7,
                       scale: float = 1.0, mesh=None, row_axes=("data",),
                       super_panels: int = 1, block_cyclic: bool = False,
@@ -482,7 +488,7 @@ def dist_tlr_cholesky(diag, u, v, ranks=None, *, tol: float = 1e-7,
     row = _row(row_axes)
     dspec = P(row, None, None)
     uvspec = P(row, "model", None, None)
-    status = init_status(diag.dtype) if track_status else None
+    status = init_status(diag.dtype, ranks) if track_status else None
     if T > 1:
         out = panel_loop(diag, u, v, ranks, T - 1, tol=tol,
                          scale=scale, mesh=mesh, dspec=dspec,
@@ -501,6 +507,7 @@ def dist_tlr_cholesky(diag, u, v, ranks=None, *, tol: float = 1e-7,
     return diag, u, v, ranks
 
 
+@jax.named_scope("repro.factorize")
 def dist_tlr_cholesky_pairs(diag, up, vp, ranks, *, layout: PairLayout,
                             tol: float = 1e-7, scale: float = 1.0, mesh=None,
                             row_axes=("data",), super_panels: int = 1,
@@ -522,7 +529,7 @@ def dist_tlr_cholesky_pairs(diag, up, vp, ranks, *, layout: PairLayout,
                                          track_status=track_status)
     dspec, pspec, _ = _pair_specs(mesh, row_axes)
     axes = pair_axis(mesh, row_axes) if shard_recompress else None
-    status = init_status(diag.dtype) if track_status else None
+    status = init_status(diag.dtype, ranks) if track_status else None
     if T > 1:
         out = pair_panel_loop(diag, up, vp, ranks, T - 1,
                               layout=layout, tol=tol,
@@ -556,7 +563,7 @@ def _tlr_cholesky_super(diag, u, v, ranks, *, tol, scale, mesh, row_axes,
     row = _row(row_axes)
     dspec = P(row, None, None)
     uvspec = P(row, "model", None, None)
-    status = init_status(diag.dtype) if track_status else None
+    status = init_status(diag.dtype, ranks) if track_status else None
 
     out_diag = jnp.zeros_like(diag)
     out_u = jnp.zeros_like(u)
@@ -615,7 +622,7 @@ def _tlr_cholesky_super_pairs(diag, up, vp, ranks, *, layout: PairLayout,
     shards = layout.n_shards
     dspec, pspec, rspec = _pair_specs(mesh, row_axes)
     axes = pair_axis(mesh, row_axes) if shard_recompress else None
-    status = init_status(diag.dtype) if track_status else None
+    status = init_status(diag.dtype, ranks) if track_status else None
 
     out_diag = jnp.zeros_like(diag)
     out_u = jnp.zeros_like(up)
@@ -668,6 +675,7 @@ def _tlr_cholesky_super_pairs(diag, up, vp, ranks, *, layout: PairLayout,
     return out_diag, out_u, out_v, out_ranks
 
 
+@jax.named_scope("repro.solve")
 def dist_tlr_solve_lower(diag_l, u, v, z):
     """Forward substitution with the TLR factor (fori_loop, masked grid) —
     the shared scan body in core.tlr (the single-device tlr_solve_lower is
@@ -675,6 +683,7 @@ def dist_tlr_solve_lower(diag_l, u, v, z):
     return solve_lower_grid(diag_l, u, v, z)
 
 
+@jax.named_scope("repro.solve")
 def dist_tlr_solve_lower_pairs(diag_l, up, vp, z, *, layout: PairLayout):
     """Forward substitution on pair-major storage: step k gathers only the
     live column-k tiles through ``layout.pos[:, k]`` (zero-filled above the
@@ -710,6 +719,7 @@ def dist_tlr_solve_lower_pairs(diag_l, up, vp, z, *, layout: PairLayout):
     return out.reshape(-1) if single else out.reshape(T * nb, r)
 
 
+@jax.named_scope("repro.solve")
 def dist_tlr_solve_upper_pairs(diag_l, up, vp, y, *, layout: PairLayout):
     """Backward substitution L^T x = y on pair-major storage (the second
     triangular solve of cokriging / alpha = Sigma^{-1} z).
@@ -742,6 +752,7 @@ def dist_tlr_solve_upper_pairs(diag_l, up, vp, y, *, layout: PairLayout):
     return out.reshape(-1) if single else out.reshape(T * nb, r)
 
 
+@jax.named_scope("repro.solve")
 def _loglik_of(diag_l, alpha, m: int,
                status: FactorStatus | None = None) -> LoglikResult:
     """Eq. 1 from the factored diagonal tiles and the forward solve.

@@ -30,6 +30,7 @@ class LoglikResult(NamedTuple):
     status: FactorStatus | None = None  # factorization health (None if untracked)
 
 
+@jax.named_scope("repro.solve")
 def loglik_from_chol(chol, z, keep_chol: bool = False,
                      status: FactorStatus | None = None) -> LoglikResult:
     """Log-likelihood given the lower Cholesky factor of Sigma.
@@ -53,7 +54,8 @@ def exact_loglik(locs, z, params: MaternParams, representation: str = "I",
     """Dense-Cholesky evaluation of Eq. (1)."""
     sigma = build_sigma(locs, params, representation=representation,
                         nugget=nugget, dists=dists)
-    chol = cholesky(sigma)
+    with jax.named_scope("repro.factorize"):
+        chol = cholesky(sigma)
     return loglik_from_chol(chol, z, keep_chol=keep_chol)
 
 

@@ -154,6 +154,16 @@ class ObjectiveAux(NamedTuple):
     clamped: jax.Array     # int32: 1 if this eval returned the penalty value
     retries: jax.Array     # int32: jitter-ladder retries this eval performed
     breakdowns: jax.Array  # int32: 1 if the clean first attempt broke
+    max_rank: jax.Array    # int32: FactorStatus.max_rank (0 for exact)
+
+    def merge(self, other: "ObjectiveAux") -> "ObjectiveAux":
+        """Running total over evaluations (``nelder_mead``'s
+        ``aux_combine``): counts add, the rank is the largest seen."""
+        return ObjectiveAux(*(op(a, b) for op, a, b in
+                              zip(_AUX_OPS, self, other)))
+
+
+_AUX_OPS = (jnp.add, jnp.add, jnp.add, jnp.maximum)   # per ObjectiveAux field
 
 
 def _backend_loglik(dists, z, params: MaternParams, cfg: MLEConfig, locs=None,
@@ -225,7 +235,8 @@ def make_objective(locs, z, cfg: MLEConfig, dists=None, with_aux=False):
     (``sqrt(finfo.max)`` — the old hardcoded ``1e12`` was *below* real
     |loglik| values at production n in f64, silently inverting the simplex
     ordering).  With ``with_aux=True`` the objective returns
-    ``(value, ObjectiveAux)`` for fault accounting (clamp/retry counters).
+    ``(value, ObjectiveAux)`` for fault accounting (clamp/retry counters)
+    and the factor's largest tile rank.
     """
     generator_direct = (cfg.backend == "tlr" and not cfg.profile and
                         (cfg.tlr_from_tiles or cfg.dist_tlr_from_tiles))
@@ -246,9 +257,11 @@ def make_objective(locs, z, cfg: MLEConfig, dists=None, with_aux=False):
                               extra_nugget=jitter)
         ll = res.loglik
         ok = jnp.isfinite(ll)
+        max_rank = jnp.zeros((), jnp.int32)
         if res.status is not None:
             ok = ok & res.status.ok
-        return ll, ok
+            max_rank = res.status.max_rank
+        return ll, ok, max_rank
 
     def neg_ll(x):
         if cfg.recovery:
@@ -257,11 +270,12 @@ def make_objective(locs, z, cfg: MLEConfig, dists=None, with_aux=False):
                                   factor=cfg.recovery_factor,
                                   max_jitter=cfg.recovery_max_jitter,
                                   max_attempts=cfg.recovery_max_attempts,
-                                  dtype=dtype)
-            ll, ok = rec.loglik, rec.ok
+                                  dtype=dtype,
+                                  aux_init=jnp.zeros((), jnp.int32))
+            ll, ok, max_rank = rec.loglik, rec.ok, rec.aux
             retries = rec.attempts - 1
         else:
-            ll, ok = eval_at(x, jnp.zeros((), dtype))
+            ll, ok, max_rank = eval_at(x, jnp.zeros((), dtype))
             retries = jnp.zeros((), jnp.int32)
         good = ok & jnp.isfinite(ll)
         penalty = jnp.asarray(jnp.finfo(dtype).max ** 0.5, dtype)
@@ -271,7 +285,8 @@ def make_objective(locs, z, cfg: MLEConfig, dists=None, with_aux=False):
         aux = ObjectiveAux(
             clamped=(~good).astype(jnp.int32),
             retries=jnp.asarray(retries, jnp.int32),
-            breakdowns=((retries > 0) | ~good).astype(jnp.int32))
+            breakdowns=((retries > 0) | ~good).astype(jnp.int32),
+            max_rank=max_rank)
         return val, aux
 
     return jax.jit(neg_ll), dists
@@ -323,15 +338,18 @@ def fit(locs, z, cfg: MLEConfig, x0=None, dists=None, n_starts: int = 1,
             for _ in range(n_starts - 1)]
         res = multistart_nelder_mead(neg_ll, x0s, max_iters=cfg.max_iters,
                                      has_aux=True,
+                                     aux_combine=ObjectiveAux.merge,
                                      checkpoint_dir=checkpoint_dir,
                                      checkpoint_every=checkpoint_every)
     elif checkpoint_dir is not None:
         res = multistart_nelder_mead(neg_ll, [x0], max_iters=cfg.max_iters,
                                      has_aux=True,
+                                     aux_combine=ObjectiveAux.merge,
                                      checkpoint_dir=checkpoint_dir,
                                      checkpoint_every=checkpoint_every)
     else:
-        res = nelder_mead(neg_ll, x0, max_iters=cfg.max_iters, has_aux=True)
+        res = nelder_mead(neg_ll, x0, max_iters=cfg.max_iters, has_aux=True,
+                          aux_combine=ObjectiveAux.merge)
     params = unpack_params(res.x, cfg.p, cfg.profile, cfg.nu_max)
     if cfg.profile:
         sigma2 = profile_variances(dists, jnp.asarray(z), params.a, params.nu,

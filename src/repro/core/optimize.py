@@ -19,8 +19,8 @@ Fault tolerance (robustness PR):
   re-centering shrink toward the best (finite) vertex instead of a normal
   step, pulling the simplex back into the feasible region.
 * ``has_aux`` threads an auxiliary pytree (clamp/retry counters from
-  ``mle.make_objective``) out of every evaluation; the running tree-sum
-  is returned on ``NMResult.aux``.
+  ``mle.make_objective``) out of every evaluation; the running total
+  (a tree-sum, or ``aux_combine``) is returned on ``NMResult.aux``.
 * ``init_state`` / ``NMResult.state`` make the loop resumable: run a
   bounded segment, checkpoint the ``NMState``, resume later —
   ``multistart_nelder_mead`` uses this for crash-tolerant multistart MLE.
@@ -83,18 +83,19 @@ def _tree_add(a, b):
     return jax.tree.map(jnp.add, a, b)
 
 
-def _eval_rows(ev, points):
+def _eval_rows(ev, points, combine=_tree_add):
     """Evaluate the rows of ``points`` one after another: one compiled
     objective, and one evaluation's memory at a time."""
     outs = [ev(x) for x in points]
     aux = outs[0][1]
     for _, a in outs[1:]:
-        aux = _tree_add(aux, a)
+        aux = combine(aux, a)
     return jnp.stack([v for v, _ in outs]), aux
 
 
 def nm_init_state(fn: Callable, x0, *, initial_radius: float = 0.25,
-                  has_aux: bool = False) -> NMState:
+                  has_aux: bool = False,
+                  aux_combine: Callable | None = None) -> NMState:
     """Build (and evaluate) the initial simplex around ``x0``.
 
     Public so checkpoint-resume callers can construct a template state with
@@ -105,7 +106,7 @@ def nm_init_state(fn: Callable, x0, *, initial_radius: float = 0.25,
     m = x0.shape[0]
     steps = initial_radius * jnp.where(jnp.abs(x0) > 1e-8, jnp.abs(x0), 1.0)
     simplex = jnp.concatenate([x0[None], x0[None] + jnp.diag(steps)], axis=0)
-    values, aux = _eval_rows(ev, simplex)
+    values, aux = _eval_rows(ev, simplex, aux_combine or _tree_add)
     simplex, values = _order(simplex, values)
     return NMState(simplex, values, jnp.asarray(m + 1), jnp.asarray(0), aux)
 
@@ -113,6 +114,7 @@ def nm_init_state(fn: Callable, x0, *, initial_radius: float = 0.25,
 def nelder_mead(fn: Callable, x0, *, max_iters: int = 200,
                 initial_radius: float = 0.25, xtol: float = 1e-6,
                 ftol: float = 1e-8, has_aux: bool = False,
+                aux_combine: Callable | None = None,
                 init_state: NMState | None = None) -> NMResult:
     """Minimize ``fn`` (scalar, jax-traceable) from x0 (shape (m,)).
 
@@ -120,17 +122,20 @@ def nelder_mead(fn: Callable, x0, *, max_iters: int = 200,
     needs, so a jitted ``fn`` is compiled once and holds one evaluation's
     memory: an MLE objective is a whole TLR factorization.
     With ``has_aux=True`` the objective returns ``(value, aux_pytree)`` and
-    the tree-sum of every evaluation's aux is returned on ``result.aux``.
+    the tree-sum of every evaluation's aux is returned on ``result.aux``;
+    ``aux_combine(total, aux)`` replaces the sum where an aux needs another
+    running total (``mle.ObjectiveAux.merge``).
     ``init_state`` resumes a previous run's ``result.state`` (the loop
     iteration/eval counters continue, so ``max_iters`` is a *total* cap).
     """
     ev = _wrap_eval(fn, has_aux)
+    combine = aux_combine or _tree_add
     x0 = jnp.asarray(x0)
     m = x0.shape[0]
 
     if init_state is None:
         init_state = nm_init_state(fn, x0, initial_radius=initial_radius,
-                                   has_aux=has_aux)
+                                   has_aux=has_aux, aux_combine=combine)
     simplex, values, aux = init_state.simplex, init_state.values, init_state.aux
     n_evals, n_iters = int(init_state.n_evals), int(init_state.n_iters)
 
@@ -148,10 +153,10 @@ def nelder_mead(fn: Callable, x0, *, max_iters: int = 200,
             # simplex toward the best vertex instead of reflecting through
             # a poisoned centroid, and re-evaluate everything.
             s = simplex[0:1] + shrink_c * (simplex - simplex[0:1])
-            v, a = _eval_rows(ev, s)
+            v, a = _eval_rows(ev, s, combine)
             simplex, values = _order(s, v)
             n_evals += m + 1
-            aux = _tree_add(aux, a)
+            aux = combine(aux, a)
             continue
 
         centroid = jnp.mean(simplex[:-1], axis=0)
@@ -161,13 +166,13 @@ def nelder_mead(fn: Callable, x0, *, max_iters: int = 200,
         xr = centroid + alpha * (centroid - worst)
         fr, a = ev(xr)
         n_evals += 1
-        aux = _tree_add(aux, a)
+        aux = combine(aux, a)
         new_pt, new_f, accepted = xr, fr, True
         if float(fr) < f_best:
             xe = centroid + gamma * (xr - centroid)
             fe, a = ev(xe)
             n_evals += 1
-            aux = _tree_add(aux, a)
+            aux = combine(aux, a)
             if float(fe) < float(fr):
                 new_pt, new_f = xe, fe
         elif float(fr) >= f_second:
@@ -180,7 +185,7 @@ def nelder_mead(fn: Callable, x0, *, max_iters: int = 200,
                 fc, a = ev(xc)
                 accepted = float(fc) < f_worst
             n_evals += 1
-            aux = _tree_add(aux, a)
+            aux = combine(aux, a)
             new_pt, new_f = xc, fc
 
         if accepted:
@@ -189,10 +194,10 @@ def nelder_mead(fn: Callable, x0, *, max_iters: int = 200,
         else:
             # Shrink toward the best vertex, which keeps its value.
             simplex = simplex[0:1] + shrink_c * (simplex - simplex[0:1])
-            v, a = _eval_rows(ev, simplex[1:])
+            v, a = _eval_rows(ev, simplex[1:], combine)
             values = jnp.concatenate([values[:1], v])
             n_evals += m
-            aux = _tree_add(aux, a)
+            aux = combine(aux, a)
         simplex, values = _order(simplex, values)
 
     final = NMState(simplex, values, jnp.asarray(n_evals),

@@ -10,10 +10,12 @@ This module holds the pieces that stop that contagion:
     A tiny pytree threaded *in-graph* through ``tlr_panel_body`` /
     ``pair_panel_loop`` alongside the factor (no host sync on the hot
     path).  It records the smallest POTRF diagonal pivot seen, a count of
-    POTRF steps whose pivot was non-positive or non-finite, and a count of
-    non-finite singular values observed by the GEMM-phase recompress.
-    ``status.ok`` is a traced scalar; ``tlr_loglik`` / ``dist_tlr_loglik``
-    use it to emit a well-defined finite sentinel instead of NaN.
+    POTRF steps whose pivot was non-positive or non-finite, a count of
+    non-finite singular values observed by the GEMM-phase recompress, and
+    the largest off-diagonal tile rank the factor held at any panel step
+    (a counter, not part of ``ok``).  ``status.ok`` is a traced scalar;
+    ``tlr_loglik`` / ``dist_tlr_loglik`` use it to emit a well-defined
+    finite sentinel instead of NaN.
 
 ``jitter_escalate``
     A do-while ``lax.while_loop`` retry ladder: evaluate an objective at
@@ -65,6 +67,9 @@ class FactorStatus(NamedTuple):
     min_pivot: jax.Array        # smallest POTRF diagonal seen (NaN -> -max)
     nonfinite_count: jax.Array  # int32: non-finite recompress singular values
     breakdown_count: jax.Array  # int32: POTRF steps with a bad pivot
+    # int32: largest off-diagonal rank: the compression's, folded with each
+    # recompression's kept ranks.  Equal to kmax when truncation hit the cap.
+    max_rank: jax.Array
 
     @property
     def ok(self) -> jax.Array:
@@ -78,9 +83,8 @@ class FactorStatus(NamedTuple):
         piv = jnp.where(jnp.isfinite(piv), piv, -_big(piv.dtype))
         worst = jnp.min(piv).astype(self.min_pivot.dtype)
         bad = (~(worst > 0)).astype(jnp.int32)
-        return FactorStatus(jnp.minimum(self.min_pivot, worst),
-                            self.nonfinite_count,
-                            self.breakdown_count + bad)
+        return self._replace(min_pivot=jnp.minimum(self.min_pivot, worst),
+                             breakdown_count=self.breakdown_count + bad)
 
     def add_nonfinite(self, count: jax.Array) -> "FactorStatus":
         """Fold a recompress non-finite singular-value count."""
@@ -88,26 +92,34 @@ class FactorStatus(NamedTuple):
             nonfinite_count=self.nonfinite_count
             + jnp.asarray(count, jnp.int32))
 
+    def fold_ranks(self, ranks: jax.Array) -> "FactorStatus":
+        """Fold the tile ranks the factor holds now (any shape, int)."""
+        top = jnp.max(ranks, initial=0).astype(jnp.int32)
+        return self._replace(max_rank=jnp.maximum(self.max_rank, top))
+
     def merge(self, other: "FactorStatus") -> "FactorStatus":
         """Combine two independent status accumulations (super-tile slices)."""
         return FactorStatus(
             jnp.minimum(self.min_pivot, other.min_pivot),
             self.nonfinite_count + other.nonfinite_count,
-            self.breakdown_count + other.breakdown_count)
+            self.breakdown_count + other.breakdown_count,
+            jnp.maximum(self.max_rank, other.max_rank))
 
     def as_dict(self) -> dict:
         """Host-side summary (concrete values only — not for traced use)."""
         return {"ok": bool(self.ok),
                 "min_pivot": float(self.min_pivot),
                 "nonfinite_count": int(self.nonfinite_count),
-                "breakdown_count": int(self.breakdown_count)}
+                "breakdown_count": int(self.breakdown_count),
+                "max_rank": int(self.max_rank)}
 
 
-def init_status(dtype=jnp.float64) -> FactorStatus:
-    """Identity element for ``FactorStatus.merge``."""
-    return FactorStatus(_big(dtype),
-                        jnp.zeros((), jnp.int32),
-                        jnp.zeros((), jnp.int32))
+def init_status(dtype=jnp.float64, ranks=None) -> FactorStatus:
+    """Identity element for ``FactorStatus.merge``; with ``ranks`` (the
+    compressed tiles' ranks) the status starts from their largest."""
+    zero = jnp.zeros((), jnp.int32)
+    status = FactorStatus(_big(dtype), zero, zero, zero)
+    return status if ranks is None else status.fold_ranks(ranks)
 
 
 class RecoveryResult(NamedTuple):
@@ -117,6 +129,7 @@ class RecoveryResult(NamedTuple):
     ok: jax.Array       # bool: did the accepted attempt factorize cleanly
     attempts: jax.Array  # int32 evaluations performed (1 == clean first try)
     jitter: jax.Array   # additive jitter used by the accepted attempt
+    aux: object = None  # the accepted attempt's aux pytree (with aux_init)
 
 
 def jitter_escalate(eval_fn: Callable[[jax.Array], tuple],
@@ -125,8 +138,13 @@ def jitter_escalate(eval_fn: Callable[[jax.Array], tuple],
                     factor: float = 10.0,
                     max_jitter: float = 1e-2,
                     max_attempts: int = 6,
-                    dtype=jnp.float64) -> RecoveryResult:
+                    dtype=jnp.float64,
+                    aux_init=None) -> RecoveryResult:
     """Evaluate ``eval_fn(jitter) -> (value, ok)`` with an escalating ladder.
+
+    With ``aux_init`` (a pytree shaped like ``eval_fn``'s extra output)
+    ``eval_fn`` returns ``(value, ok, aux)`` instead, and the accepted
+    attempt's ``aux`` is returned on ``RecoveryResult.aux``.
 
     The first attempt runs at jitter 0 (the clean path); each retry bumps
     the additive jitter ``0 -> initial -> initial*factor -> ...`` capped at
@@ -140,23 +158,25 @@ def jitter_escalate(eval_fn: Callable[[jax.Array], tuple],
     zero = jnp.zeros((), dtype)
 
     def body(state):
-        attempt, jitter, _, _, _ = state
-        val, ok = eval_fn(jitter)
+        attempt, jitter = state[:2]
+        out = eval_fn(jitter)
+        val, ok = out[:2]
+        aux = out[2] if aux_init is not None else None
         val = jnp.asarray(val, dtype)
         val = jnp.where(jnp.isfinite(val), val, sentinel_loglik(dtype))
         nxt = jnp.where(
             jitter == 0, jnp.asarray(initial, dtype),
             jnp.minimum(jitter * factor, jnp.asarray(max_jitter, dtype)))
-        return (attempt + 1, nxt, jitter, val, jnp.asarray(ok, bool))
+        return (attempt + 1, nxt, jitter, val, jnp.asarray(ok, bool), aux)
 
     def cond(state):
-        attempt, _, _, _, ok = state
+        attempt, _, _, _, ok, _ = state
         return (~ok) & (attempt < max_attempts)
 
     init = (jnp.zeros((), jnp.int32), zero, zero,
-            sentinel_loglik(dtype), jnp.zeros((), bool))
-    attempts, _, used, val, ok = jax.lax.while_loop(cond, body, init)
-    return RecoveryResult(val, ok, attempts, used)
+            sentinel_loglik(dtype), jnp.zeros((), bool), aux_init)
+    attempts, _, used, val, ok, aux = jax.lax.while_loop(cond, body, init)
+    return RecoveryResult(val, ok, attempts, used, aux)
 
 
 def find_duplicate_locations(locs, tol: float | None = None) -> list:

@@ -161,6 +161,7 @@ def truncate_core(core, tol, kmax: int, scale, left=None, right=None):
     return uu, vv, jnp.sum(keep, axis=(-2, -1)).astype(jnp.int32), s
 
 
+@jax.named_scope("repro.compress")
 def tlr_compress(sigma, tile_size: int = 0, tol: float = 1e-7,
                  max_rank: int = 0, scale=None,
                  multiple_of: int = 1, dtype_policy=None) -> TLRMatrix:
@@ -241,21 +242,25 @@ def generate_tiles(locs, params: MaternParams, tile_size: int = 0,
     T = m // nb
     panels = [locs[t * nbl:(t + 1) * nbl] for t in range(T)]
 
-    diag = jnp.stack([build_sigma_panel(panels[t], panels[t], params,
-                                        d_spatial=d_spatial, gen=gen)
-                      for t in range(T)])
+    with jax.named_scope("repro.gen"):
+        diag = jnp.stack([build_sigma_panel(panels[t], panels[t], params,
+                                            d_spatial=d_spatial, gen=gen)
+                          for t in range(T)])
     diag = apply_nugget(diag, nugget, diag.dtype)
 
     def lower_panels():
         for j in range(T - 1):
             rows = locs[(j + 1) * nbl:]
-            blk = build_sigma_panel(rows, panels[j], params,
-                                    d_spatial=d_spatial, gen=gen, block=nb)
+            with jax.named_scope("repro.gen"):
+                blk = build_sigma_panel(rows, panels[j], params,
+                                        d_spatial=d_spatial, gen=gen,
+                                        block=nb)
             yield blk.reshape(T - 1 - j, nb, nb)
 
     return diag, lower_panels(), nb, T
 
 
+@jax.named_scope("repro.compress")
 def tlr_compress_tiles(locs, params: MaternParams, tile_size: int = 0,
                        tol: float = 1e-7, max_rank: int = 0,
                        nugget: float = 0.0, gen: str = "pallas",
@@ -531,12 +536,13 @@ def tlr_panel_body(k, diag, u, v, ranks, status=None, *, tol, scale,
         du = jnp.where(act, du, 0.0)
         dv = jnp.where(act, dv, 0.0)
         u0, v0 = u[il, jl], v[il, jl]
-        if status is not None:
-            un, vn, rn, bad = _batched_recompress_stat(u0, v0, du, dv,
-                                                       tol, scale)
-            status = status.add_nonfinite(bad)
-        else:
-            un, vn, rn = _batched_recompress(u0, v0, du, dv, tol, scale)
+        with jax.named_scope("repro.recompress"):
+            if status is not None:
+                un, vn, rn, bad = _batched_recompress_stat(u0, v0, du, dv,
+                                                           tol, scale)
+                status = status.add_nonfinite(bad)
+            else:
+                un, vn, rn = _batched_recompress(u0, v0, du, dv, tol, scale)
         u = u.at[il, jl].set(jnp.where(act, un, u0))
         v = v.at[il, jl].set(jnp.where(act, vn, v0))
         ranks = ranks.at[il, jl].set(
@@ -550,12 +556,13 @@ def tlr_panel_body(k, diag, u, v, ranks, status=None, *, tol, scale,
         du = jnp.where(act, du, 0.0)
         dv = jnp.where(act, dv, 0.0)
         du = _constrain(du, mesh, uvspec)
-        if status is not None:
-            un, vn, rn, bad = _batched_recompress_stat(u, v, du, dv,
-                                                       tol, scale)
-            status = status.add_nonfinite(bad)
-        else:
-            un, vn, rn = _batched_recompress(u, v, du, dv, tol, scale)
+        with jax.named_scope("repro.recompress"):
+            if status is not None:
+                un, vn, rn, bad = _batched_recompress_stat(u, v, du, dv,
+                                                           tol, scale)
+                status = status.add_nonfinite(bad)
+            else:
+                un, vn, rn = _batched_recompress(u, v, du, dv, tol, scale)
         u = jnp.where(act, un, u)
         v = jnp.where(act, vn, v)
         ranks = jnp.where(act[..., 0, 0], rn, ranks)
@@ -563,7 +570,7 @@ def tlr_panel_body(k, diag, u, v, ranks, status=None, *, tol, scale,
     v = _constrain(v, mesh, uvspec)
     diag = _constrain(diag, mesh, dspec)
     if status is not None:
-        return diag, u, v, ranks, status
+        return diag, u, v, ranks, status.fold_ranks(ranks)
     return diag, u, v, ranks
 
 
@@ -660,14 +667,15 @@ def tlr_panel_body_bc(k, diag, up, vp, ranks, status=None, *, layout, tol,
     du = jnp.where(act, du, 0.0)
     dv = jnp.where(act, dv, 0.0)
     du = _constrain(du, mesh, pspec)
-    if status is not None:
-        un, vn, rn, bad = sharded_recompress(up, vp, du, dv, tol, scale,
-                                             mesh=mesh, axes=shard_axes,
-                                             with_count=True)
-        status = status.add_nonfinite(bad)
-    else:
-        un, vn, rn = sharded_recompress(up, vp, du, dv, tol, scale,
-                                        mesh=mesh, axes=shard_axes)
+    with jax.named_scope("repro.recompress"):
+        if status is not None:
+            un, vn, rn, bad = sharded_recompress(up, vp, du, dv, tol, scale,
+                                                 mesh=mesh, axes=shard_axes,
+                                                 with_count=True)
+            status = status.add_nonfinite(bad)
+        else:
+            un, vn, rn = sharded_recompress(up, vp, du, dv, tol, scale,
+                                            mesh=mesh, axes=shard_axes)
     up = jnp.where(act, un, up)
     vp = jnp.where(act, vn, vp)
     ranks = jnp.where(act[:, 0, 0], rn, ranks)
@@ -675,7 +683,7 @@ def tlr_panel_body_bc(k, diag, up, vp, ranks, status=None, *, layout, tol,
     vp = _constrain(vp, mesh, pspec)
     diag = _constrain(diag, mesh, dspec)
     if status is not None:
-        return diag, up, vp, ranks, status
+        return diag, up, vp, ranks, status.fold_ranks(ranks)
     return diag, up, vp, ranks
 
 
@@ -693,6 +701,7 @@ def pair_panel_loop(diag, up, vp, ranks, k_hi: int, *, layout, tol, scale,
     return indexed_scan(body, k_hi, carry)
 
 
+@jax.named_scope("repro.factorize")
 def tlr_cholesky(t: TLRMatrix, tol: float = 1e-9, scale: float = 1.0,
                  track_status: bool = False) -> TLRCholesky:
     """Factor A = L L^T keeping off-diagonal tiles compressed.
@@ -707,7 +716,7 @@ def tlr_cholesky(t: TLRMatrix, tol: float = 1e-9, scale: float = 1.0,
     """
     T = t.n_tiles
     diag, u, v, ranks = t.diag, t.u, t.v, t.ranks
-    status = init_status(diag.dtype) if track_status else None
+    status = init_status(diag.dtype, ranks) if track_status else None
     il, jl = np.tril_indices(T, k=-1)
     if len(il):
         pairs = (jnp.asarray(il), jnp.asarray(jl))
@@ -754,6 +763,7 @@ def solve_lower_grid(diag_l, u, v, z) -> jax.Array:
     return out.reshape(-1)
 
 
+@jax.named_scope("repro.solve")
 def tlr_solve_lower(chol: TLRCholesky, z) -> jax.Array:
     """Solve L alpha = z with L in TLR form (forward substitution)."""
     return solve_lower_grid(chol.diag, chol.u, chol.v, z)
@@ -802,19 +812,22 @@ def tlr_loglik_from_matrix(t: TLRMatrix, z, tol: float = 1e-9,
                            scale: float = 1.0,
                            track_status: bool = True) -> LoglikResult:
     chol = tlr_cholesky(t, tol=tol, scale=scale, track_status=track_status)
-    alpha = tlr_solve_lower(chol, z)
-    quad = jnp.sum(alpha * alpha)
-    logdet = tlr_logdet(chol)
-    m = t.shape[0]
-    ll = -0.5 * (m * math.log(2.0 * math.pi) + logdet + quad)
-    status = chol.status
-    if status is not None:
-        # Breakdown -> a well-defined finite sentinel, never NaN contagion.
-        status = status.add_nonfinite((~jnp.isfinite(ll)).astype(jnp.int32))
-        ok = status.ok
-        ll = jnp.where(ok, ll, sentinel_loglik(ll.dtype))
-        logdet = jnp.where(ok, logdet, jnp.zeros_like(logdet))
-        quad = jnp.where(ok, quad, jnp.zeros_like(quad))
+    with jax.named_scope("repro.solve"):
+        alpha = tlr_solve_lower(chol, z)
+        quad = jnp.sum(alpha * alpha)
+        logdet = tlr_logdet(chol)
+        m = t.shape[0]
+        ll = -0.5 * (m * math.log(2.0 * math.pi) + logdet + quad)
+        status = chol.status
+        if status is not None:
+            # Breakdown -> a well-defined finite sentinel, never NaN
+            # contagion.
+            status = status.add_nonfinite(
+                (~jnp.isfinite(ll)).astype(jnp.int32))
+            ok = status.ok
+            ll = jnp.where(ok, ll, sentinel_loglik(ll.dtype))
+            logdet = jnp.where(ok, logdet, jnp.zeros_like(logdet))
+            quad = jnp.where(ok, quad, jnp.zeros_like(quad))
     return LoglikResult(ll, logdet, quad, None, status)
 
 

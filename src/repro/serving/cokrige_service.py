@@ -32,11 +32,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from ..core.linalg import solve_lower
@@ -191,10 +193,12 @@ def _predict_core(factor: CokrigeFactor, pred_locs, *, interval: float,
     row = row_axes if len(row_axes) > 1 else row_axes[0]
 
     if factor.kind == "dense":
-        c0 = build_sigma_panel(factor.locs, pred_locs, params,
-                               d_spatial=factor.d_spatial,
-                               gen=gen)                       # (m, B*p)
-        w = solve_lower(factor.diag_l, c0)
+        with jax.named_scope("repro.gen"):
+            c0 = build_sigma_panel(factor.locs, pred_locs, params,
+                                   d_spatial=factor.d_spatial,
+                                   gen=gen)                   # (m, B*p)
+        with jax.named_scope("repro.solve"):
+            w = solve_lower(factor.diag_l, c0)
     else:
         T, nb = factor.diag_l.shape[0], factor.diag_l.shape[1]
         layout = pair_layout(T, factor.n_shards)
@@ -328,6 +332,10 @@ def heal_factor(factor: CokrigeFactor, cfg: CokrigeServeConfig,
         detail={"jitters_tried": tried})
 
 
+# Request numbers of ``predict_batch``'s profiler spans.
+_requests = itertools.count()
+
+
 def predict_batch(factor: CokrigeFactor, pred_locs,
                   cfg: CokrigeServeConfig = CokrigeServeConfig(),
                   mesh=None, key=None, n_draws: int = 1) -> CokrigePrediction:
@@ -339,20 +347,32 @@ def predict_batch(factor: CokrigeFactor, pred_locs,
     failed raise a structured ``ServeError`` instead of serving NaNs.
     ``cfg.degraded`` instead re-fits a broken factor via ``heal_factor``
     (the healed handle serves this request; callers wanting to keep it
-    should call ``heal_factor`` themselves)."""
-    if cfg.validate:
-        _validate_request(factor, pred_locs)
-        if not _factor_ok(factor):
-            if cfg.degraded:
-                factor = heal_factor(factor, cfg, mesh)
-            else:
-                raise ServeError(
-                    "broken_factor",
-                    "factor failed its factorization health check; re-fit "
-                    "with a larger nugget (heal_factor) or enable degraded "
-                    "mode", status=factor.status.as_dict())
-    _, predict = make_cokrige_serve_fns(cfg, mesh)
-    return predict(factor, pred_locs, key=key, n_draws=n_draws)
+    should call ``heal_factor`` themselves).
+
+    Under ``jax.profiler`` each call shows as a host span
+    ``repro.serve.predict_batch`` holding ``repro.serve.validate``,
+    ``repro.serve.status`` (the ``FactorStatus`` read-back) and
+    ``repro.serve.dispatch``, all with the call's number as ``req``."""
+    req = next(_requests)
+    with TraceAnnotation("repro.serve.predict_batch", req=req):
+        if cfg.validate:
+            with TraceAnnotation("repro.serve.validate", req=req):
+                _validate_request(factor, pred_locs)
+            with TraceAnnotation("repro.serve.status", req=req):
+                ok = _factor_ok(factor)
+            if not ok:
+                if cfg.degraded:
+                    factor = heal_factor(factor, cfg, mesh)
+                else:
+                    raise ServeError(
+                        "broken_factor",
+                        "factor failed its factorization health check; "
+                        "re-fit with a larger nugget (heal_factor) or "
+                        "enable degraded mode",
+                        status=factor.status.as_dict())
+        with TraceAnnotation("repro.serve.dispatch", req=req):
+            _, predict = make_cokrige_serve_fns(cfg, mesh)
+            return predict(factor, pred_locs, key=key, n_draws=n_draws)
 
 
 # ---------------------------------------------------------------------------

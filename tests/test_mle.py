@@ -29,6 +29,29 @@ def test_nelder_mead_quadratic_nd():
     np.testing.assert_allclose(np.asarray(res.x), np.asarray(target), atol=1e-3)
 
 
+def test_nelder_mead_aux_total_and_aux_combine():
+    """The running total of the objective's aux is a tree-sum, or what
+    ``aux_combine`` makes of it (``ObjectiveAux.merge``: counts add, the
+    rank is the largest seen)."""
+    from repro.core.mle import ObjectiveAux
+
+    def quad(x):
+        v = jnp.sum(x ** 2)
+        rank = (10 * jnp.abs(x[0])).astype(jnp.int32)
+        one = jnp.ones((), jnp.int32)
+        return v, ObjectiveAux(one, one, jnp.zeros((), jnp.int32), rank)
+
+    x0 = jnp.asarray([3.0, 1.0])
+    summed = nelder_mead(quad, x0, max_iters=5, has_aux=True)
+    merged = nelder_mead(quad, x0, max_iters=5, has_aux=True,
+                         aux_combine=ObjectiveAux.merge)
+    n = int(merged.n_evals)
+    assert int(summed.aux.clamped) == int(merged.aux.clamped) == n
+    assert int(merged.aux.breakdowns) == 0
+    assert int(merged.aux.max_rank) == 37          # 10 * 3.75, the largest
+    assert int(summed.aux.max_rank) > int(merged.aux.max_rank)
+
+
 def test_pack_unpack_roundtrip():
     params = MaternParams.bivariate(sigma11=1.3, sigma22=0.7, a=0.12,
                                     nu11=0.6, nu22=1.4, beta=-0.35)

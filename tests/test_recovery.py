@@ -60,6 +60,17 @@ def test_factor_status_algebra():
     assert d["ok"] is False and np.isfinite(d["min_pivot"])
 
 
+def test_factor_status_max_rank_is_a_counter_not_health():
+    s = init_status()
+    assert int(s.max_rank) == 0
+    s = s.fold_ranks(jnp.asarray([3, 17, 5], jnp.int32))
+    s = s.fold_ranks(jnp.asarray([[4, 9]], jnp.int32))      # never shrinks
+    assert int(s.max_rank) == 17 and bool(s.ok)
+    assert int(s.fold_ranks(jnp.zeros((0,), jnp.int32)).max_rank) == 17
+    merged = s.merge(init_status().fold_ranks(jnp.asarray([21])))
+    assert merged.as_dict()["max_rank"] == 21
+
+
 def test_sentinel_loglik_is_finite_and_orderable():
     s = sentinel_loglik(jnp.float64)
     assert np.isfinite(float(s))
@@ -110,6 +121,25 @@ def test_jitter_escalate_caps_at_max_jitter():
         lambda j: (jnp.asarray(0.0), jnp.asarray(False)),
         initial=1e-3, factor=100.0, max_jitter=1e-2, max_attempts=5)
     assert float(rec.jitter) == pytest.approx(1e-2)
+
+
+def test_jitter_escalate_carries_the_accepted_attempts_aux():
+    """With ``aux_init`` the ladder returns the accepted attempt's extra
+    output (any pytree), not a rung's that broke."""
+    def eval_at(j):
+        ok = j >= 1e-7
+        aux = {"rank": jnp.where(ok, 7, 99).astype(jnp.int32),
+               "j": j.astype(jnp.float32)}
+        return jnp.where(ok, 1.0, jnp.nan), ok, aux
+
+    init = {"rank": jnp.zeros((), jnp.int32), "j": jnp.zeros((), jnp.float32)}
+    rec = jax.jit(lambda: jitter_escalate(eval_at, initial=1e-8,
+                                          aux_init=init))()
+    assert int(rec.attempts) == 3
+    assert int(rec.aux["rank"]) == 7
+    assert float(rec.aux["j"]) == pytest.approx(1e-7)
+    assert jitter_escalate(
+        lambda j: (jnp.asarray(0.0), jnp.asarray(True))).aux is None
 
 
 def test_first_rung_recovery_matches_clean_reference():

@@ -289,3 +289,42 @@ def test_serving_8device_subprocess():
     print("SERVE_8DEV_OK", rel)
     """)
     assert "SERVE_8DEV_OK" in out
+
+
+def test_predict_batch_profiler_spans(tmp_path):
+    """Under jax.profiler one predict_batch call shows as the span
+    repro.serve.predict_batch holding validate, status and dispatch, all
+    carrying the call's ``req``; the next call carries the next number."""
+    from jax.profiler import ProfileData
+
+    from repro.serving.cokrige_service import predict_batch
+
+    locs, params = _bench_setup(8)                     # 64 locs, m = 128
+    z = simulate_mgrf(jax.random.PRNGKey(1), locs, params, nugget=1e-8)[0]
+    cfg = CokrigeServeConfig(tile_size=32, max_rank=16, tol=1e-9,
+                             nugget=1e-8)
+    factor = make_cokrige_serve_fns(cfg)[0](locs, z, params)
+    pred_locs = _pred_points(16)
+    jax.block_until_ready(predict_batch(factor, pred_locs, cfg))  # compile
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(2):
+            jax.block_until_ready(predict_batch(factor, pred_locs, cfg))
+    finally:
+        jax.profiler.stop_trace()
+    pb = max(tmp_path.rglob("*.xplane.pb"), key=os.path.getmtime)
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+              dict(e.stats).get("req"))
+             for plane in ProfileData.from_file(str(pb)).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("repro.serve.")]
+    parents = [s for s in spans if s[0] == "repro.serve.predict_batch"]
+    assert len(parents) == 2
+    assert parents[1][3] == parents[0][3] + 1
+    for name, lo, hi, req in parents:
+        kids = sorted((s for s in spans if s[0] != name and s[3] == req),
+                      key=lambda s: s[1])
+        assert [k[0] for k in kids] == ["repro.serve.validate",
+                                        "repro.serve.status",
+                                        "repro.serve.dispatch"]
+        assert all(lo <= k[1] <= k[2] <= hi for k in kids)

@@ -169,6 +169,29 @@ def test_mle_objective_block_cyclic_matches_masked():
     assert float(obj_bc(x)) == pytest.approx(float(obj_masked(x)), rel=1e-9)
 
 
+
+def test_objective_aux_reports_max_rank():
+    """ObjectiveAux.max_rank is the TLR factor's largest tile rank: at
+    least the compression's, at most kmax; the exact backend reports 0."""
+    from repro.core.dist_tlr import dist_compress_tiles
+    from repro.distribution.block_cyclic import pair_layout
+
+    locs = _locs(8)
+    params = MaternParams.bivariate(a=0.09, nu11=0.6, nu22=1.2, beta=0.4)
+    z = simulate_mgrf(jax.random.PRNGKey(0), locs, params, nugget=1e-8)[0]
+    cfg = MLEConfig(p=2, profile=False, backend="tlr", tile_size=32,
+                    tlr_max_rank=12, nugget=1e-8, morton=False,
+                    dist_tlr_from_tiles=True, block_cyclic=True)
+    x = pack_params(params, profile=False)
+    _, aux = make_objective(locs, z, cfg, with_aux=True)[0](x)
+    t = dist_compress_tiles(locs, params, tile_size=32, max_rank=12,
+                            nugget=1e-8, layout=pair_layout(4, 1))
+    assert int(aux.clamped) == 0
+    assert int(jnp.max(t.ranks)) <= int(aux.max_rank) <= 12
+    exact = dataclasses.replace(cfg, backend="exact")
+    _, aux = make_objective(locs, z, exact, with_aux=True)[0](x)
+    assert int(aux.max_rank) == 0
+
 def test_mle_objective_generator_direct_skips_dense_distances(monkeypatch):
     """Non-profile generator-direct backends never build the (n, n) distance
     matrix — at production n it would be the fit's largest allocation."""
