@@ -21,8 +21,9 @@ Two placements for the strict-lower UV tiles (DESIGN.md §2,4):
                                      P(("data", "model")) — length ~ T^2/2
 
     the ExaGeoStat/PaRSEC schedule (Abdulah et al. 2018; arXiv:1804.09137):
-    only the live strict-lower tasks are batched (~2.4x less QR/SVD work
-    per step), the cyclic deal keeps every device's share of the live
+    each panel step recompresses only a static window that holds its live
+    pairs (block_cyclic.window_schedule: at most 2 live + 2 S pairs on S
+    shards), the cyclic deal keeps every device's share of the live
     trailing submatrix balanced as panels retire, and the (T, T) grid is
     never materialized (~2x less tile storage).  Per-step communication is
     the panel-column broadcast through ``layout.pos[:, k]``, which the
@@ -443,9 +444,10 @@ def dist_tlr_cholesky(diag, u, v, ranks=None, *, tol: float = 1e-7,
     but one trace regardless of T.
 
     ``block_cyclic = True``: the static strict-lower pair batch on
-    block-cyclic pair-major storage (core.tlr.tlr_panel_body_bc) — ~2.4x
-    less recompression work per step and load-balanced live pairs on every
-    device; the grid inputs are converted once at entry and back at exit.
+    block-cyclic pair-major storage (core.tlr.tlr_panel_body_bc) — each
+    step recompresses only a window holding its live pairs, load-balanced
+    on every device; the grid inputs are converted once at entry and back
+    at exit.
 
     ``super_panels = S > 1``: python-unrolled outer loop over S shrinking
     sub-matrices, fori_loop inside — the batch only spans the live trailing

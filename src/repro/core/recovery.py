@@ -11,7 +11,8 @@ This module holds the pieces that stop that contagion:
     ``pair_panel_loop`` alongside the factor (no host sync on the hot
     path).  It records the smallest POTRF diagonal pivot seen, a count of
     POTRF steps whose pivot was non-positive or non-finite, a count of
-    non-finite singular values observed by the GEMM-phase recompress, and
+    non-finite singular values observed by the GEMM-phase recompress (on
+    the pair path: over each step's live-pair window, not every slot), and
     the largest off-diagonal tile rank the factor held at any panel step
     (a counter, not part of ``ok``).  ``status.ok`` is a traced scalar;
     ``tlr_loglik`` / ``dist_tlr_loglik`` use it to emit a well-defined

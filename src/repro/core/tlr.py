@@ -48,8 +48,9 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding
 
+from ..distribution.block_cyclic import window_schedule
 from ..distribution.compress_svd import svd_truncate_batch
-from ..distribution.pair_qr import sharded_recompress
+from ..distribution.pair_qr import window_recompress
 from .linalg import cholesky, qr, right_svd, solve_lower
 from .covariance import MaternParams, build_sigma, build_sigma_panel
 from .likelihood import LoglikResult
@@ -574,8 +575,8 @@ def tlr_panel_body(k, diag, u, v, ranks, status=None, *, tol, scale,
     return diag, u, v, ranks
 
 
-def indexed_scan(body, k_hi: int, carry):
-    """fori_loop(0, k_hi) with an s32 induction variable that reverse-mode
+def indexed_scan(body, k_hi: int, carry, k_lo: int = 0):
+    """fori_loop(k_lo, k_hi) with an s32 induction variable that reverse-mode
     AD can handle: one lax.scan over a static int32 arange.
 
     Two constraints meet here.  The SPMD partitioner rejects mixed s64/s32
@@ -589,7 +590,8 @@ def indexed_scan(body, k_hi: int, carry):
     def step(c, k):
         return body(k, c), None
 
-    carry, _ = lax.scan(step, carry, jnp.arange(k_hi, dtype=jnp.int32))
+    carry, _ = lax.scan(step, carry,
+                        jnp.arange(k_lo, k_hi, dtype=jnp.int32))
     return carry
 
 
@@ -607,8 +609,8 @@ def panel_loop(diag, u, v, ranks, k_hi: int, *, tol, scale, pairs=None,
     return indexed_scan(body, k_hi, carry)
 
 
-def tlr_panel_body_bc(k, diag, up, vp, ranks, status=None, *, layout, tol,
-                      scale, mesh=None, dspec=None, pspec=None,
+def tlr_panel_body_bc(k, diag, up, vp, ranks, status=None, *, layout, width,
+                      tol, scale, mesh=None, dspec=None, pspec=None,
                       shard_axes=None):
     """One right-looking panel step k on *pair-major* strict-lower storage
     (distribution.block_cyclic.PairLayout): the static strict-lower pair
@@ -616,25 +618,25 @@ def tlr_panel_body_bc(k, diag, up, vp, ranks, status=None, *, layout, tol,
 
     ``up``/``vp`` are (length, nb, kmax) with the leading axis laid out
     block-cyclically over the devices (pspec), so the GEMM + recompress —
-    the dominant work — is a purely local batch of length/S pairs per
-    shard, load-balanced at every k.  The only per-step communication is
-    the panel-column gather/scatter through ``layout.pos[:, k]`` (the
-    broadcast of column k that the right-looking algorithm needs anyway).
-    Compared with the masked full-grid body (tlr_panel_body, pairs=None)
-    this recompresses ~T(T-1)/2 instead of T^2 tiles per step (~2.4x less
-    QR/SVD work) and never materializes the (T, T) grid.
+    the dominant work — is a purely local batch per shard.  The only
+    per-step communication is the panel-column gather/scatter through
+    ``layout.pos[:, k]`` (the broadcast of column k that the right-looking
+    algorithm needs anyway).  The (T, T) grid is never materialized.
+
+    The GEMM + recompress runs on a static window, the last ``width`` local
+    slots of every shard, which must hold every pair this step updates
+    (i > j > k; ``block_cyclic.window_schedule`` picks it); inside it the
+    pads and the pairs of retired columns are masked.  ``width=0`` skips
+    the GEMM and the recompress: POTRF, TRSM and SYRK still run.
 
     ``shard_axes`` names the mesh axes the pair axis is laid out over:
     the recompress QR/SVD then runs under shard_map so each device
-    factorizes only its own ~length/S slots (distribution/pair_qr.py) —
-    without it GSPMD replicates the whole (length, nb, 2k) QR batch on
-    every device.  None keeps the replicated batch (the mesh=None /
-    fallback path).
+    factorizes only its own window (distribution/pair_qr.py) — without it
+    GSPMD replicates the whole QR batch on every device.  None keeps the
+    replicated batch (the mesh=None / fallback path).
     """
     T, nb = diag.shape[0], diag.shape[1]
     rows = jnp.arange(T)
-    il = jnp.asarray(layout.il)
-    jl = jnp.asarray(layout.jl)
     pos = jnp.asarray(layout.pos)
     # ---- POTRF on tile (k, k): replicated small factorization.
     dkk = lax.dynamic_index_in_dim(diag, k, 0, keepdims=False)
@@ -659,26 +661,25 @@ def tlr_panel_body_bc(k, diag, up, vp, ranks, status=None, *, layout, tol,
     upd = jnp.einsum("tnk,tkl,tml->tnm", uk, w, uk)
     diag = diag - jnp.where(below, upd, 0.0)
     diag = jnp.where(row_is_k, lkk[None], diag)
-    # ---- GEMM + recompress over the pair list (local per shard).
-    wij = jnp.einsum("lnk,lnq->lkq", vk[il], vk[jl])          # V_ik^T V_jk
-    du = jnp.einsum("lnk,lkq->lnq", uk[il], wij)              # U_ik W
-    dv = -uk[jl]
-    act = ((il > jl) & (jl > k))[:, None, None]     # pads fail il > jl
-    du = jnp.where(act, du, 0.0)
-    dv = jnp.where(act, dv, 0.0)
-    du = _constrain(du, mesh, pspec)
-    with jax.named_scope("repro.recompress"):
+    # ---- GEMM + recompress over the window's pair slots (local per shard).
+    if width:
+        S, pps = layout.n_shards, layout.pairs_per_shard
+        slots = (np.arange(S)[:, None] * pps
+                 + np.arange(pps - width, pps)[None, :]).ravel()
+        il, jl = layout.il[slots], layout.jl[slots]
+        wij = jnp.einsum("lnk,lnq->lkq", vk[il], vk[jl])      # V_ik^T V_jk
+        du = jnp.einsum("lnk,lkq->lnq", uk[il], wij)          # U_ik W
+        dv = -uk[jl]
+        act = jnp.asarray(il > jl) & (jnp.asarray(jl) > k)  # pads fail il > jl
+        du = jnp.where(act[:, None, None], du, 0.0)
+        dv = jnp.where(act[:, None, None], dv, 0.0)
+        du = _constrain(du, mesh, pspec)
+        with jax.named_scope("repro.recompress"):
+            up, vp, ranks, bad = window_recompress(
+                up, vp, ranks, du, dv, act, tol, scale, n_shards=S,
+                mesh=mesh, axes=shard_axes)
         if status is not None:
-            un, vn, rn, bad = sharded_recompress(up, vp, du, dv, tol, scale,
-                                                 mesh=mesh, axes=shard_axes,
-                                                 with_count=True)
             status = status.add_nonfinite(bad)
-        else:
-            un, vn, rn = sharded_recompress(up, vp, du, dv, tol, scale,
-                                            mesh=mesh, axes=shard_axes)
-    up = jnp.where(act, un, up)
-    vp = jnp.where(act, vn, vp)
-    ranks = jnp.where(act[:, 0, 0], rn, ranks)
     up = _constrain(up, mesh, pspec)
     vp = _constrain(vp, mesh, pspec)
     diag = _constrain(diag, mesh, dspec)
@@ -690,15 +691,21 @@ def tlr_panel_body_bc(k, diag, up, vp, ranks, status=None, *, layout, tol,
 def pair_panel_loop(diag, up, vp, ranks, k_hi: int, *, layout, tol, scale,
                     mesh=None, dspec=None, pspec=None, shard_axes=None,
                     status=None):
-    """indexed_scan of the block-cyclic pair body for k in [0, k_hi)."""
-    def body(k, carry):
-        return tlr_panel_body_bc(k, *carry, layout=layout, tol=tol,
-                                 scale=scale, mesh=mesh, dspec=dspec,
-                                 pspec=pspec, shard_axes=shard_axes)
-
+    """The block-cyclic pair body for k in [0, k_hi): one indexed_scan per
+    segment of ``block_cyclic.window_schedule(layout, k_hi)``, each with
+    its static window width, so a step recompresses only the window that
+    holds its live pairs."""
     carry = (diag, up, vp, ranks) if status is None else \
         (diag, up, vp, ranks, status)
-    return indexed_scan(body, k_hi, carry)
+    for seg in window_schedule(layout, k_hi).segments:
+        def body(k, c, width=seg.width):
+            return tlr_panel_body_bc(k, *c, layout=layout, width=width,
+                                     tol=tol, scale=scale, mesh=mesh,
+                                     dspec=dspec, pspec=pspec,
+                                     shard_axes=shard_axes)
+
+        carry = indexed_scan(body, seg.k1, carry, k_lo=seg.k0)
+    return carry
 
 
 @jax.named_scope("repro.factorize")

@@ -17,7 +17,7 @@ Layout contract (``pair_layout``):
 
   * pairs are enumerated column-major — (1,0), (2,0), ..., (T-1,0), (2,1),
     ... — so the pairs a panel step k retires (column j = k) form a prefix
-    of the enumeration;
+    of the enumeration, and the pairs it updates (j > k) the suffix;
   * enumeration index q is placed at slot ``(q % S) * pairs_per_shard +
     (q // S)`` for S shards.  Standard contiguous sharding of the leading
     axis then gives shard d the cyclically-dealt pairs {d, d+S, d+2S, ...},
@@ -26,6 +26,11 @@ Layout contract (``pair_layout``):
     columns die, which contiguous (block) placement cannot do;
   * the list is zero-padded to a multiple of S with (0, 0) entries, which
     fail the strict-lower predicate ``il > jl`` and are masked everywhere.
+
+Under the cyclic deal that suffix is also a suffix of every shard's local
+slots (the pads sit at the local ends), so a panel step need recompress
+only the last ``b`` local slots of each shard: ``window_schedule`` picks
+those static widths.
 
 ``pos`` inverts the map: ``pos[i, j]`` is the slot of strict-lower pair
 (i, j), and ``length`` (one past the end — genuinely out-of-bounds, since
@@ -46,7 +51,8 @@ import jax.numpy as jnp
 
 __all__ = ["PairLayout", "pair_layout", "pair_shards", "pair_axis",
            "grid_to_pairs", "pairs_to_grid", "slice_positions",
-           "column_owner_tables", "owned_pair_tables"]
+           "column_owner_tables", "owned_pair_tables", "WindowSegment",
+           "WindowSchedule", "window_schedule"]
 
 
 class PairLayout(NamedTuple):
@@ -223,3 +229,67 @@ def slice_positions(outer: PairLayout, inner: PairLayout, offset: int
     keep = inner.valid
     src[keep] = outer.pos[inner.il[keep] + offset, inner.jl[keep] + offset]
     return src
+
+
+class WindowSegment(NamedTuple):
+    """Panel steps ``[k0, k1)`` that recompress the last ``width`` local
+    slots of every shard (0: no recompression at all)."""
+
+    k0: int
+    k1: int
+    width: int
+
+
+class WindowSchedule(NamedTuple):
+    """Static live-pair windows of one pair panel loop.
+
+    ``computed`` counts the pair-recompressions the windows run (every
+    shard's ``width`` slots at every step); ``live`` those the steps need
+    (pairs i > j > k).  A loop that recompresses every slot at every step
+    computes ``k_hi * layout.length``.
+    """
+
+    segments: tuple
+    computed: int
+    live: int
+
+
+@functools.lru_cache(maxsize=None)
+def _window_schedule(n_tiles: int, n_shards: int, k_hi: int):
+    layout = pair_layout(n_tiles, n_shards)
+    S, pps = layout.n_shards, layout.pairs_per_shard
+    segments, computed, live_total = [], 0, 0
+    for k in range(k_hi):
+        live = (layout.valid & (layout.jl > k)).reshape(S, pps)
+        n_live = int(live.sum())
+        # the live slots of a shard are a suffix of its local slots: the
+        # window must reach back to the earliest first live slot
+        need = max((pps - int(np.argmax(row)) for row in live if row.any()),
+                   default=0)
+        width = segments[-1].width if segments else 0
+        if segments and not (width > 0 and (
+                2 * need <= width or S * width > 2 * n_live + 2 * S)):
+            segments[-1] = segments[-1]._replace(k1=k + 1)
+        else:
+            segments.append(WindowSegment(k, k + 1, need))
+        computed += S * segments[-1].width
+        live_total += n_live
+    return WindowSchedule(tuple(segments), computed, live_total)
+
+
+def window_schedule(layout: PairLayout, k_hi: int) -> WindowSchedule:
+    """Segments of panel steps ``[0, k_hi)`` with one static window width
+    each, covering every live pair (i > j > k) of every step.
+
+    A step's live pairs are a suffix of each shard's local slots, so the
+    width it needs is the longest such suffix over the shards.  Widths only
+    fall as k grows; a segment keeps its first step's width and a new one
+    starts when the need falls to half of it, or when the segment would
+    compute more than ``2 * live + 2 * S`` pairs, so there are O(log T)
+    segments, each one compiled scan body.  A step with no live pair gets
+    width 0.  All static, from (n_tiles, n_shards, k_hi) alone: at
+    T = 4, S = 1 the widths are (3, 1, 0) and 4 of 4 live
+    pair-recompressions are computed, where the unwindowed loop computes
+    18.
+    """
+    return _window_schedule(layout.n_tiles, layout.n_shards, int(k_hi))

@@ -28,6 +28,12 @@ caller that disables padding (``pad=False``) gets the replicated batch plus
 a one-time ``RuntimeWarning`` (``warn_fallback_once``) so the perf cliff is
 never silent.  ``distribution/compress_svd.py`` reuses the same helpers for
 the compression-phase truncation SVDs.
+
+``window_recompress`` is the form the block-cyclic panel loop runs: it
+recompresses only a static window, the last ``b`` local slots of every
+shard (``distribution.block_cyclic.window_schedule``), slicing and writing
+back inside the ``shard_map`` so the window never gathers another device's
+slots.
 """
 from __future__ import annotations
 
@@ -39,7 +45,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["pair_shard_count", "pad_leading", "warn_fallback_once",
-           "sharded_recompress"]
+           "sharded_recompress", "window_recompress"]
 
 _warned_fallbacks: set[str] = set()
 
@@ -144,3 +150,73 @@ def sharded_recompress(up, vp, du, dv, tol, scale, *, mesh=None, axes=None,
                        out_specs=(spec, spec, P(axes)), check_vma=False)
     un, vn, rn = fn(up, vp, du, dv, scale)
     return un[:length], vn[:length], rn[:length]
+
+
+def _window_update(up, vp, ranks, du, dv, act, blocks: int, recompress):
+    """Recompress the last ``b`` slots of each of ``blocks`` equal blocks of
+    the pair axis (``du``/``dv``/``act`` hold the ``blocks * b`` window
+    slots, block-major) and write them back where ``act``; every other slot
+    passes through.  Returns (up, vp, ranks, bad)."""
+    pps = up.shape[0] // blocks
+    b = du.shape[0] // blocks
+
+    def take(x):
+        x = x.reshape((blocks, pps) + x.shape[1:])[:, pps - b:]
+        return x.reshape((blocks * b,) + x.shape[2:])
+
+    def put(x, w):
+        blk = x.reshape((blocks, pps) + x.shape[1:])
+        blk = blk.at[:, pps - b:].set(w.reshape((blocks, b) + x.shape[1:]))
+        return blk.reshape(x.shape)
+
+    u0, v0, r0 = take(up), take(vp), take(ranks)
+    un, vn, rn, bad = recompress(u0, v0, du, dv)
+    keep = act[:, None, None]
+    return (put(up, jnp.where(keep, un, u0)), put(vp, jnp.where(keep, vn, v0)),
+            put(ranks, jnp.where(act, rn, r0)), bad)
+
+
+def window_recompress(up, vp, ranks, du, dv, act, tol, scale, *,
+                      n_shards: int, mesh=None, axes=None):
+    """Recompress a static window of the block-cyclic pair axis in place.
+
+    ``up``/``vp``/``ranks`` are the whole (length, ...) pair arrays, in
+    ``n_shards`` local blocks of ``length / n_shards`` slots (the layout's
+    deal).  ``du``/``dv``/``act`` hold only the window, ``n_shards * b``
+    slots: block d's last ``b`` local slots, block-major.  Each window slot
+    gets ``_batched_recompress`` of its old tile plus its update where
+    ``act`` is set and keeps its old tile otherwise; slots outside the
+    window are not touched.  Returns (up, vp, ranks, bad), ``bad`` the int32
+    count of non-finite core singular values over the window's slots.
+
+    When the mesh axes span exactly ``n_shards`` devices, each device
+    slices, recompresses and writes back its own window inside one
+    ``shard_map``: no collective but the sum of ``bad``.  ``mesh=None``
+    slices the blocks on the one device; a mesh of another shard count
+    takes the window globally and runs ``sharded_recompress`` on it.
+    """
+    from ..core.tlr import _batched_recompress_stat
+
+    axes = tuple(axes) if axes else ()
+    if mesh is None or not axes:
+        return _window_update(
+            up, vp, ranks, du, dv, act, n_shards,
+            lambda *a: _batched_recompress_stat(*a, tol, scale))
+    if pair_shard_count(mesh, axes) != n_shards:
+        return _window_update(
+            up, vp, ranks, du, dv, act, n_shards,
+            lambda *a: sharded_recompress(*a, tol, scale, mesh=mesh,
+                                          axes=axes, with_count=True))
+
+    def local(u, v, r, du_l, dv_l, act_l, sc):
+        *out, bad = _window_update(
+            u, v, r, du_l, dv_l, act_l, 1,
+            lambda *a: _batched_recompress_stat(*a, tol, sc))
+        return (*out, bad[None])          # (1,) per shard -> (S,) global
+
+    spec, vec = P(axes, None, None), P(axes)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(spec, spec, vec, spec, spec, vec, P()),
+                       out_specs=(spec, spec, vec, vec), check_vma=False)
+    up, vp, ranks, bad = fn(up, vp, ranks, du, dv, act, jnp.asarray(scale))
+    return up, vp, ranks, jnp.sum(bad)

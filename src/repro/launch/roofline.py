@@ -265,13 +265,16 @@ def tlr_pair_update_stats(n_tiles: int, super_panels: int = 1,
       masked  — the masked full-grid batch recompresses every (T', T') slot
                 of the live slice each step: the paper-faithful baseline,
                 ~6x live at S = 1.
-      pair    — the static strict-lower pair batch (block-cyclic placement):
-                C(T', 2) slots padded to a multiple of n_shards, ~2.4x live.
+      pair    — the block-cyclic pair batch: each step recompresses only
+                the static live-pair window of every shard
+                (block_cyclic.window_schedule), at most 2x live + 2 S a step.
 
     ``super_panels = S > 1`` shrinks the live slice every outer step for
     both forms.  Counts are whole-factorization task counts (multiply by
     the per-task recompress cost for flops).
     """
+    from ..distribution.block_cyclic import pair_layout, window_schedule
+
     T, S = n_tiles, max(super_panels, 1)
     assert T % S == 0, (T, S)
     chunk = T // S
@@ -280,10 +283,8 @@ def tlr_pair_update_stats(n_tiles: int, super_panels: int = 1,
     for s in range(S):
         ts = T - s * chunk                       # live slice width
         steps = chunk - 1 if s == S - 1 else chunk
-        n_pairs = ts * (ts - 1) // 2
-        padded = -(-n_pairs // n_shards) * n_shards if n_pairs else 0
         masked += steps * ts * ts
-        pair += steps * padded
+        pair += window_schedule(pair_layout(ts, n_shards), steps).computed
     return dict(
         live_updates=live, masked_updates=masked, pair_updates=pair,
         masked_overcompute=masked / max(live, 1),

@@ -1,5 +1,6 @@
 """Block-cyclic pair placement (distribution/block_cyclic.py): layout
-invariants, grid round-trips, and live-pair load balance."""
+invariants, grid round-trips, live-pair load balance, and the live-pair
+windows of the pair panel loop."""
 import numpy as np
 import pytest
 
@@ -7,7 +8,8 @@ import jax.numpy as jnp
 
 from repro.distribution.block_cyclic import (grid_to_pairs, pair_axis,
                                              pair_layout, pair_shards,
-                                             pairs_to_grid, slice_positions)
+                                             pairs_to_grid, slice_positions,
+                                             window_schedule)
 
 
 @pytest.mark.parametrize("T,S", [(2, 1), (6, 1), (6, 4), (8, 8), (9, 5),
@@ -72,3 +74,44 @@ def test_slice_positions_trailing_submatrix():
 def test_pair_shards_and_axis_off_mesh():
     assert pair_shards(None) == 1
     assert pair_axis(None) is None
+
+
+@pytest.mark.parametrize("S", [1, 2, 4, 8])
+def test_window_schedule_covers_every_live_pair(S):
+    """For T <= 16 and every loop length a super-step can ask for: the
+    segments tile [0, k_hi), every live pair (i > j > k) of every step lies
+    in the last ``width`` local slots of its shard, a step computes at most
+    2 live + 2 S pair-recompressions (none when nothing is live), and the
+    counters add up."""
+    for T in range(1, 17):
+        lay = pair_layout(T, S)
+        pps = lay.pairs_per_shard
+        local = np.arange(lay.length) % pps
+        for k_hi in sorted({max(T - 1, 0), T // 2}):
+            sched = window_schedule(lay, k_hi)
+            steps = [k for seg in sched.segments for k in range(seg.k0, seg.k1)]
+            assert steps == list(range(k_hi)), (T, S, k_hi, sched)
+            assert len(sched.segments) <= 2 * T.bit_length() + 1
+            computed = live_total = 0
+            for seg in sched.segments:
+                for k in range(seg.k0, seg.k1):
+                    live = lay.valid & (lay.jl > k)
+                    n_live = int(live.sum())
+                    assert (local[live] >= pps - seg.width).all(), (T, S, k)
+                    assert S * seg.width <= 2 * n_live + 2 * S, (T, S, k)
+                    if n_live == 0:
+                        assert seg.width == 0, (T, S, k)
+                    computed += S * seg.width
+                    live_total += n_live
+            assert (sched.computed, sched.live) == (computed, live_total)
+
+
+def test_window_schedule_four_tiles_one_shard():
+    """The one-chip TLR7 cell's layout (T = 4, one shard): widths 3, 1, 0,
+    so the loop computes the 4 live pair-recompressions and no more (the
+    unwindowed loop computed 3 steps x 6 slots = 18)."""
+    lay = pair_layout(4, 1)
+    sched = window_schedule(lay, 3)
+    assert [(s.k0, s.k1, s.width) for s in sched.segments] == \
+        [(0, 1, 3), (1, 2, 1), (2, 3, 0)]
+    assert (sched.computed, sched.live) == (4, 4)

@@ -106,6 +106,29 @@ def test_zero_shard_detected_dist_path():
     assert np.isfinite(float(broken.loglik))
 
 
+@pytest.mark.parametrize("row", [1, 2, 3])
+def test_nan_column0_pair_detected_pair_path(row):
+    """A NaN in a column-0 pair (row, 0) on the pair path: the panel loop
+    never recompresses that slot (pair (i, j) enters the live window only
+    at steps k < j), but step 0 carries the NaN into the trailing diagonal
+    tiles and the window's updates, so the factor is still flagged."""
+    from repro.distribution.block_cyclic import pair_layout, window_schedule
+
+    locs, z = _setup()
+    lay = pair_layout(4, 1)                 # m = 128, 32-row tiles
+    slot = int(lay.pos[row, 0])
+    for seg in window_schedule(lay, 3).segments:
+        assert slot < lay.pairs_per_shard - seg.width
+    kw = dict(locs=locs, params=_PARAMS, from_tiles=True, nugget=_NUGGET,
+              block_cyclic=True, **_TLR_KW)
+    with nan_compress_panel(panel=slot):
+        broken = dist_tlr_loglik(z=z, **kw)
+    st = broken.status
+    assert not bool(st.ok)
+    assert int(st.nonfinite_count) + int(st.breakdown_count) >= 1
+    assert np.isfinite(float(broken.loglik))
+
+
 def test_jitter_ladder_heals_singular_sigma():
     """The real-world recoverable fault: duplicate locations at nugget 0
     make Sigma exactly singular.  The ladder's first rung heals, and the

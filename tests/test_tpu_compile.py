@@ -112,3 +112,24 @@ def test_sharded_recompress_spreads_pairs(topo, one_chip, tpu_forms):
     sharded = run(mesh, NamedSharding(mesh, P(axes, None, None)))
     replicated = run(None, NamedSharding(mesh, P()))
     assert sharded < replicated / 2, (sharded, replicated)
+
+
+def test_window_recompress_stays_on_its_shard(topo, tpu_forms):
+    """The live-pair window of a 2x2 mesh's block-cyclic pair axis is
+    sliced, recompressed and written back on each device: the compiled
+    program gathers no other device's slots."""
+    from repro.distribution.pair_qr import window_recompress
+    from repro.launch.mesh import auto_mesh
+
+    mesh = auto_mesh((2, 2), ("data", "model"), devices=topo.devices)
+    axes = ("data", "model")
+    pairs = NamedSharding(mesh, P(axes, None, None))
+    slots = NamedSharding(mesh, P(axes))
+    full = _spec((16, 256, 32), jnp.float64, pairs)     # 4 slots a device
+    win = _spec((8, 256, 32), jnp.float64, pairs)       # the last 2 of each
+    fn = jax.jit(lambda u, v, r, du, dv, act: window_recompress(
+        u, v, r, du, dv, act, 1e-7, 1.0, n_shards=4, mesh=mesh, axes=axes))
+    text = fn.lower(full, full, _spec((16,), jnp.int32, slots), win, win,
+                    _spec((8,), jnp.bool_, slots)).compile().as_text()
+    for op in ("all-gather", "all-to-all", "collective-permute"):
+        assert op not in text, op
